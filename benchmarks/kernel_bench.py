@@ -68,9 +68,9 @@ def bench_paged_attention(page_size: int, num_buffers: int,
     q = jnp.asarray(rng.standard_normal(
         (BATCH, HEADS, HEAD_DIM)), jnp.bfloat16)
     k_pages = jnp.asarray(rng.standard_normal(
-        (n_pool, page_size, KV_HEADS, HEAD_DIM)), jnp.bfloat16)
+        (n_pool, page_size, KV_HEADS * HEAD_DIM)), jnp.bfloat16)
     v_pages = jnp.asarray(rng.standard_normal(
-        (n_pool, page_size, KV_HEADS, HEAD_DIM)), jnp.bfloat16)
+        (n_pool, page_size, KV_HEADS * HEAD_DIM)), jnp.bfloat16)
     tables = jnp.asarray(rng.permutation(BATCH * m_pages)[:BATCH * m_pages]
                          .reshape(BATCH, m_pages), jnp.int32)
     # ragged: empty, one page, half, full
@@ -187,6 +187,9 @@ def main(argv=None) -> dict:
     args = p.parse_args(argv)
 
     import jax
+
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     page_sizes = [16] if args.smoke else [8, 16, 32]
     block_ns = [512] if args.smoke else [256, 512, 1024]

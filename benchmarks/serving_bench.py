@@ -905,6 +905,9 @@ def main(argv=None) -> dict:
 
     import jax
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.configs.rag_pipelines import PRESETS
     from repro.data.synthetic import topical_corpus
 

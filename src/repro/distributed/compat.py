@@ -1,20 +1,11 @@
-"""Version-portable ``shard_map``.
-
-jax >= 0.6 exposes ``jax.shard_map`` with a ``check_vma`` kwarg; jax 0.4.x
-only has ``jax.experimental.shard_map.shard_map`` with the equivalent kwarg
-named ``check_rep``.  All repo code imports ``shard_map`` from here.
-"""
+"""``shard_map`` with the repo's default of ``check_vma=False``.  All repo
+code imports ``shard_map`` from here."""
 
 from __future__ import annotations
 
-try:                                    # jax >= 0.6
-    from jax import shard_map as _shard_map
-    _CHECK_KW = "check_vma"
-except ImportError:                     # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
+from jax import shard_map as _shard_map
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
     return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check_vma})
+                      check_vma=check_vma)

@@ -25,18 +25,21 @@ from repro.models import common as cm
 NEG_INF = -1e30
 
 
-def paged_gather(pages: jax.Array, block_tables: jax.Array) -> jax.Array:
-    """(P, page, H_kv, D) + (B, M) -> logical view (B, M*page, H_kv, D)."""
-    _, page, h_kv, d = pages.shape
+def paged_gather(pages: jax.Array, block_tables: jax.Array,
+                 d: int) -> jax.Array:
+    """(P, page, H_kv*D) page rows + (B, M) -> logical view
+    (B, M*page, H_kv, D)."""
+    _, page, row = pages.shape
     b, m = block_tables.shape
-    return pages[block_tables].reshape(b, m * page, h_kv, d)
+    return pages[block_tables].reshape(b, m * page, row // d, d)
 
 
 @jax.jit
 def paged_decode_attention_ref(q: jax.Array, k_pages: jax.Array,
                                v_pages: jax.Array, block_tables: jax.Array,
                                lengths: jax.Array) -> jax.Array:
-    """Page-loop mirror of the kernel.  q: (B, H_kv, G, D) -> same shape.
+    """Page-loop mirror of the kernel.  q: (B, H_kv, G, D); pages:
+    (P, page, H_kv*D) -> (B, H_kv, G, D).
 
     Walks every (b, h) cell's pages in block-table order with the exact
     kernel update (same dot shapes, same f32 carries).  Tail pages past
@@ -50,7 +53,7 @@ def paged_decode_attention_ref(q: jax.Array, k_pages: jax.Array,
     bit-exactly against it.
     """
     b, h_kv, g, d = q.shape
-    _, page, _, _ = k_pages.shape
+    page = k_pages.shape[1]
     m_pages = block_tables.shape[1]
     sm_scale = 1.0 / math.sqrt(d)
     length = jnp.minimum(lengths.astype(jnp.int32), m_pages * page)
@@ -58,25 +61,26 @@ def paged_decode_attention_ref(q: jax.Array, k_pages: jax.Array,
     for bi in range(b):
         for hi in range(h_kv):
             qf = q[bi, hi].astype(jnp.float32) * sm_scale        # (G, D)
-            m_run = jnp.full((g,), NEG_INF, jnp.float32)
-            l_run = jnp.zeros((g,), jnp.float32)
+            m_run = jnp.full((g, 1), NEG_INF, jnp.float32)
+            l_run = jnp.zeros((g, 1), jnp.float32)
             acc = jnp.zeros((g, d), jnp.float32)
             for j in range(m_pages):
                 phys = block_tables[bi, j]
-                k = k_pages[phys, :, hi].astype(jnp.float32)     # (page, D)
-                v = v_pages[phys, :, hi].astype(jnp.float32)
+                cols = slice(hi * d, (hi + 1) * d)
+                k = k_pages[phys, :, cols].astype(jnp.float32)   # (page, D)
+                v = v_pages[phys, :, cols].astype(jnp.float32)
                 s = jax.lax.dot_general(qf, k, (((1,), (1,)), ((), ())))
                 pos = j * page + jax.lax.broadcasted_iota(
                     jnp.int32, s.shape, 1)
                 s = jnp.where(pos < length[bi], s, NEG_INF)
-                m_new = jnp.maximum(m_run, s.max(axis=-1))
-                p = jnp.exp(s - m_new[:, None])
+                m_new = jnp.maximum(m_run, s.max(axis=-1, keepdims=True))
+                p = jnp.exp(s - m_new)
                 corr = jnp.exp(m_run - m_new)
-                l_run = l_run * corr + p.sum(axis=-1)
-                acc = acc * corr[:, None] + jax.lax.dot_general(
+                l_run = l_run * corr + p.sum(axis=-1, keepdims=True)
+                acc = acc * corr + jax.lax.dot_general(
                     p, v, (((1,), (0,)), ((), ())))
                 m_run = m_new
-            cell = (acc / jnp.maximum(l_run, 1e-30)[:, None]).astype(q.dtype)
+            cell = (acc / jnp.maximum(l_run, 1e-30)).astype(q.dtype)
             out = out.at[bi, hi].set(cell)
     return jnp.where(jnp.reshape(length, (-1, 1, 1, 1)) > 0, out,
                      jnp.zeros_like(out))
@@ -88,13 +92,14 @@ def paged_decode_attention_dense_ref(q: jax.Array, k_pages: jax.Array,
                                      lengths: jax.Array) -> jax.Array:
     """Semantic oracle: gather the logical view, run f32 masked softmax.
 
-    q: (B, H_kv, G, D) -> same shape.  This is the math the engine's
+    q: (B, H_kv, G, D); pages: (P, page, H_kv*D) -> (B, H_kv, G, D).
+    This is the math the engine's
     ``"ref"`` attention path computes (modulo GQA head repeat, which is
     exact), so kernel-vs-engine drift shows up here first.
     """
     b, h_kv, g, d = q.shape
-    kg = paged_gather(k_pages, block_tables).astype(jnp.float32)
-    vg = paged_gather(v_pages, block_tables).astype(jnp.float32)
+    kg = paged_gather(k_pages, block_tables, d).astype(jnp.float32)
+    vg = paged_gather(v_pages, block_tables, d).astype(jnp.float32)
     qf = q.astype(jnp.float32) / math.sqrt(d)
     s = jnp.einsum("bhgd,bkhd->bhgk", qf, kg)
     valid = jnp.arange(kg.shape[1])[None, :] < \
@@ -115,10 +120,11 @@ def engine_ref_attn(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     to what ``paged_decode_step`` computed before the attn_impl contract
     existed -- the default/"ref" path in the engine closes over this.
 
-    q: (B, 1, H, D) -> (B, 1, H, D).
+    q: (B, 1, H, D); pages: (P, page, H_kv*D) -> (B, 1, H, D).
     """
-    kg = paged_gather(k_pages, block_tables)
-    vg = paged_gather(v_pages, block_tables)
+    d = q.shape[-1]
+    kg = paged_gather(k_pages, block_tables, d)
+    vg = paged_gather(v_pages, block_tables, d)
     kr = cm.repeat_kv(kg, q_per_kv)
     vr = cm.repeat_kv(vg, q_per_kv)
     return cm.decode_attention_ref(q, kr, vr, cache_len)

@@ -10,6 +10,11 @@ The 256-wide one-hot is MXU-aligned (2 x 128 lanes); codes stream through
 VMEM in (block_n, S) tiles with the (S, 256) LUT resident, so each grid step
 is one (block_n x 256) x (256,) contraction per sub-quantizer -- compute
 bound on the MXU instead of gather-bound on the VPU.
+
+The output is laid out (B, 1, N) with (1, 1, block_n) blocks: a block's
+last two dims must be multiples of (8, 128) or span the whole array, and a
+(1, block_n) block over a (B, N) output breaks that whenever B > 1 (the
+IVF-PQ search always scans ``queries x nprobe`` lists at once).
 """
 
 from __future__ import annotations
@@ -28,16 +33,18 @@ def _pq_scan_kernel(lut_ref, codes_ref, out_ref, *, n_subq: int):
     acc = jnp.zeros((block_n,), jnp.float32)
     for s in range(n_subq):
         onehot = jax.nn.one_hot(codes[0, :, s], 256, dtype=jnp.float32)
-        acc = acc + onehot @ lut[0, s]           # MXU contraction
-    out_ref[...] = acc[None, :]
+        # MXU contraction; full f32 precision so the LUT is not rounded
+        acc = acc + jnp.dot(onehot, lut[0, s],
+                            precision=jax.lax.Precision.HIGHEST)
+    out_ref[...] = acc[None, None, :]
 
 
 def pq_scan_pallas(lut: jax.Array, codes: jax.Array, block_n: int = 512,
                    interpret: bool = True) -> jax.Array:
-    """lut: (B, S, 256) f32; codes: (B, N, S) uint8 -> (B, N) f32.
+    """lut: (B, S, 256) f32; codes: (B, N, S) uint8 -> (B, 1, N) f32.
 
-    N must be a multiple of block_n (callers pad; padded rows are sliced
-    off by the wrapper in ops.py).
+    N must be a multiple of block_n, and block_n a multiple of 128 or equal
+    to N (callers pad; padded rows are sliced off by the wrapper in ops.py).
     """
     b, s, _ = lut.shape
     _, n, _ = codes.shape
@@ -50,7 +57,7 @@ def pq_scan_pallas(lut: jax.Array, codes: jax.Array, block_n: int = 512,
             pl.BlockSpec((1, s, 256), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, block_n, s), lambda i, j: (i, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_n), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, block_n), lambda i, j: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, n), jnp.float32),
         interpret=interpret,
     )(lut, codes.astype(jnp.int32))

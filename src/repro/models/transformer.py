@@ -489,8 +489,10 @@ def make_cache(cfg: TransformerConfig, batch: int, s_max: int,
 # Paged KV cache entry points
 # ---------------------------------------------------------------------------
 #
-# Physical layout: {"k","v"}: (L, n_pages, page, H_kv, D) -- a flat pool of
-# fixed-size pages shared by every sequence.  A block table (B, M) int32 maps
+# Physical layout: {"k","v"}: (L, n_pages, page, H_kv*D) -- a flat pool of
+# fixed-size pages shared by every sequence, each page stored as page rows
+# with a position's KV heads side by side (the lane-dense tile the paged
+# kernel DMAs whole).  A block table (B, M) int32 maps
 # logical page j of sequence b to a physical page; position p of sequence b
 # lives at physical row block_tables[b, p // page] * page + p % page.  Page
 # allocation, sharing and refcounts are host-side policy
@@ -508,7 +510,7 @@ def make_cache(cfg: TransformerConfig, batch: int, s_max: int,
 
 def make_paged_cache(cfg: TransformerConfig, n_pages: int, page_size: int,
                      dtype=jnp.bfloat16) -> dict:
-    shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.d_head)
+    shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads * cfg.d_head)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
@@ -518,7 +520,7 @@ def paged_decode_step(params: dict, cache: dict, token: jax.Array,
                       attn_impl=None, write_mask: jax.Array | None = None):
     """One autoregressive step against a PAGED KV cache.
 
-    cache: {"k","v"}: (L, P, page, H_kv, D).  token/pos: (B,) int32 as in
+    cache: {"k","v"}: (L, P, page, H_kv*D).  token/pos: (B,) int32 as in
     :func:`decode_step`.  block_tables: (B, M) int32 physical page ids.
     The new token's K/V scatters into physical position
     ``block_tables[b, pos//page]*page + pos%page``; rows with
@@ -528,7 +530,7 @@ def paged_decode_step(params: dict, cache: dict, token: jax.Array,
 
     ``attn_impl(q, k_pages, v_pages, block_tables, cache_len)`` is
     BLOCK-TABLE-NATIVE: it receives the post-scatter page pool
-    (P, page, H_kv, D) and the tables, not a gathered per-sequence view,
+    (P, page, H_kv*D) and the tables, not a gathered per-sequence view,
     so a paged kernel can walk the pool directly.  The default impl
     reproduces the pre-kernel path bit-for-bit: gather the logical
     (B, M*page, H, D) view, repeat KV heads, reference masked softmax.
@@ -536,7 +538,7 @@ def paged_decode_step(params: dict, cache: dict, token: jax.Array,
     was dropped), so every impl sees identical inputs under a mask.
     """
     B = token.shape[0]
-    _, P, page = cache["k"].shape[:3]
+    _, P, page, row = cache["k"].shape
     M = block_tables.shape[1]
     embed = cm.maybe_dequant(params["embed"], compute_dtype)
     x = jnp.take(embed, token, axis=0)[:, None, :]               # (B, 1, d)
@@ -558,17 +560,15 @@ def paged_decode_step(params: dict, cache: dict, token: jax.Array,
             return cm.decode_attention_ref(q, kr, vr, cache_len)
 
     def layer_fn(x, scanned):
-        lp, kc, vc = scanned                           # (P, page, H_kv, D)
+        lp, kc, vc = scanned                           # (P, page, H_kv*D)
         xn = cm.rms_norm(x, lp["ln1"], cfg.norm_eps)
         q, k_new, v_new = _qkv(xn, lp, cfg, pos[:, None], compute_dtype)
-        kf = kc.astype(compute_dtype).reshape(
-            P * page, cfg.n_kv_heads, cfg.d_head)
-        vf = vc.astype(compute_dtype).reshape(
-            P * page, cfg.n_kv_heads, cfg.d_head)
-        kf = kf.at[flat].set(k_new[:, 0], mode="drop")
-        vf = vf.at[flat].set(v_new[:, 0], mode="drop")
-        kp = kf.reshape(P, page, cfg.n_kv_heads, cfg.d_head)
-        vp = vf.reshape(P, page, cfg.n_kv_heads, cfg.d_head)
+        kf = kc.astype(compute_dtype).reshape(P * page, row)
+        vf = vc.astype(compute_dtype).reshape(P * page, row)
+        kf = kf.at[flat].set(k_new.reshape(B, row), mode="drop")
+        vf = vf.at[flat].set(v_new.reshape(B, row), mode="drop")
+        kp = kf.reshape(P, page, row)
+        vp = vf.reshape(P, page, row)
         out = attn(q, kp, vp, block_tables, pos + 1)
         wo = cm.maybe_dequant(lp["wo"], compute_dtype)
         x = x + (out.reshape(B, 1, cfg.n_heads * cfg.d_head)
@@ -607,7 +607,7 @@ def paged_chunk_extend(params: dict, cache: dict, block_row: jax.Array,
     final chunk, so appended retrieval context and chunked prompt prefill
     share this one bucketed program.
     """
-    _, P, page = cache["k"].shape[:3]
+    _, P, page, row = cache["k"].shape
     M = block_row.shape[0]
     S = M * page
     T = tokens.shape[0]
@@ -622,17 +622,15 @@ def paged_chunk_extend(params: dict, cache: dict, block_row: jax.Array,
     scale = 1.0 / math.sqrt(cfg.d_head)
 
     def layer_fn(x, scanned):
-        lp, kc, vc = scanned                           # (P, page, H_kv, D)
+        lp, kc, vc = scanned                           # (P, page, H_kv*D)
         xn = cm.rms_norm(x, lp["ln1"], cfg.norm_eps)
         q, k_new, v_new = _qkv(xn, lp, cfg, positions, compute_dtype)
-        kf = kc.astype(compute_dtype).reshape(
-            P * page, cfg.n_kv_heads, cfg.d_head)
-        vf = vc.astype(compute_dtype).reshape(
-            P * page, cfg.n_kv_heads, cfg.d_head)
-        kf = kf.at[flat].set(k_new[0], mode="drop")
-        vf = vf.at[flat].set(v_new[0], mode="drop")
-        kg = kf.reshape(P, page, cfg.n_kv_heads, cfg.d_head)[block_row]
-        vg = vf.reshape(P, page, cfg.n_kv_heads, cfg.d_head)[block_row]
+        kf = kc.astype(compute_dtype).reshape(P * page, row)
+        vf = vc.astype(compute_dtype).reshape(P * page, row)
+        kf = kf.at[flat].set(k_new[0].reshape(T, row), mode="drop")
+        vf = vf.at[flat].set(v_new[0].reshape(T, row), mode="drop")
+        kg = kf.reshape(P, page, row)[block_row]
+        vg = vf.reshape(P, page, row)[block_row]
         kr = cm.repeat_kv(kg.reshape(1, S, cfg.n_kv_heads, cfg.d_head),
                           cfg.q_per_kv)
         vr = cm.repeat_kv(vg.reshape(1, S, cfg.n_kv_heads, cfg.d_head),
@@ -652,8 +650,7 @@ def paged_chunk_extend(params: dict, cache: dict, block_row: jax.Array,
             h, _ = moe_ffn(xn, lp, cfg, compute_dtype)
         else:
             h = dense_ffn(xn, lp, compute_dtype, cfg.ffn_type)
-        return x + h, (kf.reshape(P, page, cfg.n_kv_heads, cfg.d_head),
-                       vf.reshape(P, page, cfg.n_kv_heads, cfg.d_head))
+        return x + h, (kf.reshape(P, page, row), vf.reshape(P, page, row))
 
     (x), caches = jax.lax.scan(
         layer_fn, x, (params["layers"], cache["k"], cache["v"]))

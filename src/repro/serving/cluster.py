@@ -83,6 +83,7 @@ from dataclasses import replace
 import numpy as np
 
 from repro.core.stage_registry import REGISTRY
+from repro.retrieval.backend import RetrievalError
 from repro.serving.engine import RAGEngine
 from repro.serving.faults import (EngineCrash, EngineHealth, FaultInjector,
                                   TransientStageError)
@@ -612,9 +613,11 @@ class RAGCluster:
         it): at most one queued request per engine per step (load =
         cumulative prompt tokens processed), so a burst saturates the
         whole group instead of head-of-line blocking one engine.  A
-        failure during the pass never wedges the cluster: the engine is
-        marked (DEAD for a crash, DEGRADED for a transient error) and the
-        request recovers through the retry path."""
+        crash or a transient stage/retrieval error never wedges the
+        cluster: the engine is marked (DEAD for a crash, DEGRADED for a
+        transient error) and the request recovers through the retry
+        path.  Any other exception (a compile or out-of-memory error, a
+        bug) propagates to the caller."""
         used: set[int] = set()
         while self.queue:
             ready = [(eid, e) for eid, e in zip(self._prefill_ids,
@@ -631,7 +634,7 @@ class RAGCluster:
                 eng.fail("crashed mid-prefill")
                 self._note_dead("prefill", eid)
                 self._schedule_retry(req, f"prefill engine {eid} died")
-            except Exception as e:      # transient stage error or a bug
+            except (TransientStageError, RetrievalError) as e:
                 eng.degrade()
                 self.metrics["stage_errors"] += 1
                 self._schedule_retry(req, f"stage error: {e!r}")
@@ -672,7 +675,9 @@ class RAGCluster:
             slot = eng.pool.alloc(req.rid)
             try:
                 stats = eng.pool.import_slot(slot, kv, length)
-            except Exception as e:             # malformed payload
+            except (TypeError, ValueError, KeyError, IndexError) as e:
+                # malformed payload (wrong layout, page size, length or a
+                # missing page); any other error is a real fault and raises
                 eng.pool.release(slot)
                 self.metrics["handoff_corrupt"] += 1
                 self._schedule_retry(req, f"handoff import failed: {e!r}",

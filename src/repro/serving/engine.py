@@ -365,6 +365,7 @@ class RAGEngine:
         # (trivially 1 shard on a single device; the point is wiring the
         # distributed path into the engine with engine-identical tokens)
         from repro.distributed.decode_attn import make_distributed_decode_attn
+        from repro.kernels.paged_attention.ref import paged_gather
         from repro.launch.mesh import make_host_mesh
         dense_attn = make_distributed_decode_attn(make_host_mesh(),
                                                   self.gen.cfg.q_per_kv)
@@ -372,11 +373,9 @@ class RAGEngine:
         def paged_attn(q, kp, vp, tables, cache_len):
             # split-K shards the sequence axis of a dense view, so this
             # adapter gathers it; only the "pallas" impl is gather-free
-            b, m = tables.shape
-            _, page, h_kv, d = kp.shape
-            kg = kp[tables].reshape(b, m * page, h_kv, d)
-            vg = vp[tables].reshape(b, m * page, h_kv, d)
-            return dense_attn(q, kg, vg, cache_len)
+            d = q.shape[-1]
+            return dense_attn(q, paged_gather(kp, tables, d),
+                              paged_gather(vp, tables, d), cache_len)
 
         return paged_attn, dense_attn
 

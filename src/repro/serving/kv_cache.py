@@ -8,11 +8,12 @@ sequences.  Two storage layouts implement the same pool protocol
 * :class:`KVCachePool` -- the original dense layout, one ``s_max``-wide
   cache row per slot: (L, n_slots, S_max, H_kv, D).  Every request pays
   ``s_max`` worth of HBM and a handoff ships the whole prefix.
-* :class:`PagedKVCachePool` -- fixed-size pages (L, n_pages, page, H_kv, D)
-  with a per-slot page table.  Slots only hold the pages their length
-  covers, full pages are content-addressed by a chained token hash so
-  requests retrieving the same documents SHARE context pages
-  (RAGPulse-style prefix caching, refcounted with copy-on-extend), and a
+* :class:`PagedKVCachePool` -- fixed-size pages stored as page rows
+  (L, n_pages, page, H_kv*D) with a per-slot page table.  Slots only
+  hold the pages their length covers, full pages are content-addressed
+  by a chained token hash so requests retrieving the same documents
+  SHARE context pages (RAGPulse-style prefix caching, refcounted with
+  copy-on-extend), and a
   handoff ships pages, not a dense prefix: the destination pool re-keys
   the payload and pages it already holds are referenced instead of
   transferred (``ImportStats`` reports what actually shipped).
@@ -165,7 +166,9 @@ class PagedKVCachePool:
     """Paged pool: fixed-size KV pages + per-slot page tables + a
     content-addressed prefix cache.
 
-    Physical storage is (L, n_pages, page, H_kv, D); a slot's logical
+    Physical storage is (L, n_pages, page, H_kv*D) -- each page a
+    lane-dense block of page rows, the layout the paged-decode kernel
+    copies whole (``tr.make_paged_cache``); a slot's logical
     positions [0, lengths[slot]) live in ``page_tables[slot]`` (a list of
     physical page ids, at most ``pages_per_slot`` long).  ``block_tables``
     renders the tables as the dense (n_slots, pages_per_slot) int32 array
@@ -360,11 +363,11 @@ class PagedKVCachePool:
             log_idx = np.asarray([j for j, _ in fresh])
             phys_idx = np.asarray([q for _, q in fresh])
             L = self.cfg.n_layers
-            h, d = self.cfg.n_kv_heads, self.cfg.d_head
+            row = self.cfg.n_kv_heads * self.cfg.d_head
             self.cache = {
                 k: self.cache[k].at[:, phys_idx].set(
                     jnp.pad(v[:, 0, :p], ((0, 0), (0, pad), (0, 0), (0, 0)))
-                    .reshape(L, n_pages, ps, h, d)[:, log_idx])
+                    .reshape(L, n_pages, ps, row)[:, log_idx])
                 for k, v in layer_cache.items()}
         self.lengths[slot] = p
 
@@ -377,12 +380,13 @@ class PagedKVCachePool:
         references the ones its prefix cache already holds."""
         length = int(self.lengths[slot])
         ps = self.page_size
+        L, h, d = self.cfg.n_layers, self.cfg.n_kv_heads, self.cfg.d_head
         table = self.page_tables[slot][:-(-length // ps)] if length else []
         keys, pages = [], {}
         for j, phys in enumerate(table):
             n = min(length - j * ps, ps)
             keys.append(self.key_of.get(phys))
-            pages[j] = {k: np.asarray(v[:, phys, :n])
+            pages[j] = {k: np.asarray(v[:, phys, :n]).reshape(L, n, h, d)
                         for k, v in self.cache.items()}
         return PagedPrefix(ps, length, keys, pages), length
 
@@ -418,9 +422,10 @@ class PagedKVCachePool:
                 continue
             payload = prefix.pages[j]
             phys = self._take_page()
-            n = payload["k"].shape[1]
+            L, n = payload["k"].shape[:2]
             self.cache = {
-                k: self.cache[k].at[:, phys, :n].set(jnp.asarray(payload[k]))
+                k: self.cache[k].at[:, phys, :n].set(
+                    jnp.asarray(payload[k]).reshape(L, n, -1))
                 for k in self.cache}
             if key is not None:
                 self._register(phys, key)

@@ -5,8 +5,8 @@ with the exact update order the kernel uses, so bf16 runs -- the serving
 dtype -- are gated BIT-EXACTLY against it; f32 runs compile with
 different fusion context and are gated at a few-ulp allclose.  Every
 configuration is additionally checked (allclose) against the dense
-semantic oracle, and the engine-facing tests prove the ``attn_impl``
-knob is token-for-token invisible.
+semantic oracle, and the engine-facing tests hold every ``attn_impl``
+to the reference's decode logits along the reference trajectory.
 
 Tier structure: kernel-level tests run the interpret-mode kernel on tiny
 shapes and are fast; anything building a ``RAGEngine`` is ``slow``.
@@ -37,8 +37,8 @@ def _problem(b, h_kv, g, d, page, m_pages, lengths, dtype=jnp.bfloat16,
     rng = np.random.default_rng(seed)
     n_pool = b * m_pages + 1
     q = jnp.asarray(rng.standard_normal((b, h_kv, g, d)), dtype)
-    k = jnp.asarray(rng.standard_normal((n_pool, page, h_kv, d)), dtype)
-    v = jnp.asarray(rng.standard_normal((n_pool, page, h_kv, d)), dtype)
+    k = jnp.asarray(rng.standard_normal((n_pool, page, h_kv * d)), dtype)
+    v = jnp.asarray(rng.standard_normal((n_pool, page, h_kv * d)), dtype)
     if tables is None:
         tables = rng.permutation(b * m_pages).reshape(b, m_pages)
     tables = jnp.asarray(tables, jnp.int32)
@@ -189,7 +189,7 @@ def test_mirror_matches_dense_oracle_f32():
     dense = paged_decode_attention_dense_ref(q, k, v, tables, lens)
     np.testing.assert_allclose(np.asarray(mirror), np.asarray(dense),
                                rtol=0, atol=1e-5)
-    view = paged_gather(k, tables)
+    view = paged_gather(k, tables, 16)
     assert view.shape == (4, 32, 2, 16)
 
 
@@ -213,7 +213,7 @@ def test_write_mask_rows_identical_under_kernel_attn():
     n_pages = b * m + 1
     rng = np.random.default_rng(7)
     cache = {kk: jnp.asarray(rng.standard_normal(
-        (cfg.n_layers, n_pages, page, cfg.n_kv_heads, cfg.d_head)),
+        (cfg.n_layers, n_pages, page, cfg.n_kv_heads * cfg.d_head)),
         jnp.bfloat16) for kk in ("k", "v")}
     tables = jnp.asarray(rng.permutation(b * m).reshape(b, m), jnp.int32)
     token = jnp.asarray([3, 5, 7], jnp.int32)
@@ -277,18 +277,77 @@ def stack():
     return gen, enc, corpus, make_q
 
 
-def _run(stack, attn_kw, preset_kw, questions):
+def _run(stack, attn_kw, preset_kw, questions, hook=None):
     from repro.serving.engine import EngineConfig, RAGEngine
     from repro.serving.request import Request, State
     gen, enc, corpus, _ = stack
     cfg = EngineConfig(**{"decode_slots": 3, "s_max": 96,
                           "max_new_tokens": 6, **preset_kw, **attn_kw})
     engine = RAGEngine(gen, enc, corpus, cfg)
+    if hook is not None:
+        hook(engine)
     # the SAME questions every run: make_q samples randomly per call
     reqs = [Request(question=q.copy()) for q in questions]
     engine.serve(reqs)
     assert all(r.state is State.DONE for r in reqs)
     return [r.output for r in reqs], engine
+
+
+def _steer_by_ref(engine, gaps, margins):
+    """Run ``engine``'s own fused paged decode program beside the
+    reference on every step, with the reference driving the trajectory.
+
+    Each step feeds the same inputs to (a) the reference step (gather +
+    softmax attention), (b) a step that returns its logits through the
+    attention callable the engine's serving program closes over, and (c)
+    the engine's real donated fused program, on a copy of the pool.  The
+    reference's tokens and pool drive the run, so every impl is held
+    against the same trajectory.  Per step, over the stepping rows,
+    ``gaps`` gets the largest |logit(b) - logit(a)| and ``margins`` the
+    largest reference-logit deficit of the token the engine's program
+    chose (0 where it picked the reference's argmax)."""
+    cfg = engine.gen.cfg
+    real = engine._paged_decode_jit
+    impl = real.__wrapped__.keywords["attn"]
+
+    def step(attn):
+        return jax.jit(lambda p, c, t, pos, bt, m: tr.paged_decode_step(
+            p, c, t, pos, bt, cfg, attn_impl=attn, write_mask=m))
+
+    ref_step, impl_step = step(None), step(impl)
+
+    def steered(params, cache, tok, pos, tables, mask):
+        log_ref, cache_ref = ref_step(params, cache, tok, pos, tables, mask)
+        log_impl, _ = impl_step(params, cache, tok, pos, tables, mask)
+        toks, _ = real(params, jax.tree_util.tree_map(jnp.copy, cache),
+                       tok, pos, tables, mask)
+        rows = np.asarray(mask)
+        v = cfg.vocab_size
+        ref = np.asarray(log_ref[:, :v], np.float32)
+        gap = np.abs(ref - np.asarray(log_impl[:, :v], np.float32))
+        gaps.append(float(gap[rows].max()))
+        chosen = np.take_along_axis(ref, np.asarray(toks)[:, None], -1)[:, 0]
+        margins.append(float((ref.max(-1) - chosen)[rows].max()))
+        return jnp.argmax(log_ref[:, :v], -1).astype(jnp.int32), cache_ref
+
+    engine._paged_decode_jit = steered
+
+
+# Decode-logit tolerance against the reference path.  The reference casts
+# its f32 softmax probabilities to bf16 before the PV product; the Pallas
+# kernel keeps them in f32 through an online softmax, and split-K
+# normalizes its bf16 partial outputs after the PV product.  The rounding
+# gap reaches the logits as a few bf16 ulps of their ~O(1) magnitude --
+# enough to flip a near-tie argmax on random weights: on the iterative
+# preset pallas, quad-buffered pallas and splitk all emit 52 where the
+# reference emits 51 (request 2, token 2), so the reference's greedy
+# tokens cannot be their gate there.  Worst readings on these stacks
+# (baseline / iterative): gap 0.039 / 0.047 pallas, 0.041 / 0.051 splitk,
+# margin 0 everywhere.  A planted kernel fault (each KV head reading its
+# neighbour's lanes) reads gap 4.7 / 5.3 and margin 5.4 / 4.8.  If every
+# logit is within LOGIT_ATOL, the impl's argmax sits at most 2*LOGIT_ATOL
+# below the reference's top logit, which bounds the margin.
+LOGIT_ATOL = 0.1
 
 
 @pytest.mark.slow
@@ -298,22 +357,32 @@ def _run(stack, attn_kw, preset_kw, questions):
      "max_new_tokens": 9},                                 # iterative preset
 ], ids=["baseline", "iterative"])
 def test_attn_impl_token_parity(stack, kw):
-    """attn_impl is a pure execution-strategy knob: the Pallas kernel
-    (double- and quad-buffered) and the split-K distributed path emit
-    token streams identical to the gather+softmax reference."""
+    """attn_impl is a pure execution-strategy knob.  Double- and
+    quad-buffered Pallas runs emit identical token streams (staging depth
+    never changes values), and on the baseline preset the same tokens as
+    the gather+softmax reference.  Along the reference trajectory, the
+    Pallas kernel's and the split-K path's decode logits match the
+    reference within ``LOGIT_ATOL``, and the token each engine's own fused
+    program picks is within the matching margin of the reference's."""
     _, _, _, make_q = stack
     questions = [make_q(i % 4) for i in range(5)]
     out_ref, eng_ref = _run(stack, {"attn_impl": "ref"}, kw, questions)
     out_pal, eng_pal = _run(stack, {"attn_impl": "pallas"}, kw, questions)
     out_q4, _ = _run(stack, {"attn_impl": "pallas",
                              "attn_num_buffers": 4}, kw, questions)
-    out_spl, eng_spl = _run(stack, {"attn_impl": "splitk"}, kw, questions)
-    assert out_pal == out_ref
-    assert out_q4 == out_ref
-    assert out_spl == out_ref
+    assert out_q4 == out_pal
+    if not kw:
+        assert out_pal == out_ref
     assert eng_ref.metrics_snapshot()["attn_impl"] == "ref"
     assert eng_pal.metrics_snapshot()["attn_impl"] == "pallas"
-    assert eng_spl.metrics_snapshot()["attn_impl"] == "splitk"
+    for impl in ("pallas", "splitk"):
+        gaps, margins = [], []
+        _, eng = _run(stack, {"attn_impl": impl}, kw, questions,
+                      hook=lambda e: _steer_by_ref(  # noqa: B023
+                          e, gaps, margins))
+        assert gaps and max(gaps) <= LOGIT_ATOL, (impl, max(gaps))
+        assert max(margins) <= 2 * LOGIT_ATOL, (impl, max(margins))
+        assert eng.metrics_snapshot()["attn_impl"] == impl
 
 
 @pytest.mark.slow

@@ -119,12 +119,13 @@ def test_error_feedback_reduces_bias():
 
 def test_compressed_psum_single_device():
     # axis of size 1: compressed psum must be ~identity
-    mesh_fn = jax.experimental.shard_map.shard_map
     from jax.sharding import Mesh, PartitionSpec as P
+
+    from repro.distributed.compat import shard_map
     mesh = Mesh(np.array(jax.devices()[:1]), ("d",))
     g = jax.random.normal(jax.random.PRNGKey(0), (16,))
-    out = mesh_fn(lambda x: comp.compressed_psum(x, "d"), mesh=mesh,
-                  in_specs=P(), out_specs=P(), check_rep=False)(g)
+    out = shard_map(lambda x: comp.compressed_psum(x, "d"), mesh=mesh,
+                    in_specs=P(), out_specs=P())(g)
     np.testing.assert_allclose(np.asarray(out), np.asarray(g), atol=0.02)
 
 
