@@ -18,6 +18,14 @@ import jax.numpy as jnp
 Params = Any  # pytree of jnp arrays
 
 
+def named(name: str, fn):
+    """``fn`` (a function or a ``partial``) renamed to ``name``: jitted, it
+    compiles to the XLA module ``jit_<name>``, which is how the device
+    trace's ``XLA Modules`` line names each run of it."""
+    fn.__name__ = name
+    return fn
+
+
 # ---------------------------------------------------------------------------
 # Initializers
 # ---------------------------------------------------------------------------

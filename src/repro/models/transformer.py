@@ -284,37 +284,43 @@ def forward(params: dict, tokens: jax.Array, cfg: TransformerConfig,
     between layers (Megatron-SP style activation sharding).
     """
     B, S = tokens.shape
-    embed = cm.maybe_dequant(params["embed"], compute_dtype)
-    x = jnp.take(embed, tokens, axis=0)
+    with jax.named_scope("embed"):
+        embed = cm.maybe_dequant(params["embed"], compute_dtype)
+        x = jnp.take(embed, tokens, axis=0)
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
 
     def layer_fn(carry, lp):
         x, aux = carry
         if sp_spec is not None:
             x = jax.lax.with_sharding_constraint(x, sp_spec)
-        h, k, v = _attn_full_seq(
-            cm.rms_norm(x, lp["ln1"], cfg.norm_eps), lp, cfg, positions,
-            compute_dtype)
-        x = x + h
-        xn = cm.rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.moe is not None:
-            h, a = moe_ffn(xn, lp, cfg, compute_dtype)
-            aux = aux + a
-        else:
-            h = dense_ffn(xn, lp, compute_dtype, cfg.ffn_type)
-        x = x + h
+        with jax.named_scope("attention"):
+            h, k, v = _attn_full_seq(
+                cm.rms_norm(x, lp["ln1"], cfg.norm_eps), lp, cfg, positions,
+                compute_dtype)
+            x = x + h
+        with jax.named_scope("ffn"):
+            xn = cm.rms_norm(x, lp["ln2"], cfg.norm_eps)
+            if cfg.moe is not None:
+                h, a = moe_ffn(xn, lp, cfg, compute_dtype)
+                aux = aux + a
+            else:
+                h = dense_ffn(xn, lp, compute_dtype, cfg.ffn_type)
+            x = x + h
         ys = (k, v) if collect_cache else None
         return (x, aux), ys
 
     if remat:
         layer_fn = jax.checkpoint(layer_fn)
-    (x, aux), caches = jax.lax.scan(layer_fn, (x, jnp.zeros((), jnp.float32)),
-                                    params["layers"])
-    x = cm.rms_norm(x, params["ln_f"], cfg.norm_eps)
-    if return_hidden:
-        return x
-    head = cm.maybe_dequant(params["head"], compute_dtype)
-    logits = x.astype(compute_dtype) @ head
+    # the scan's own slicing and its stacked K/V outputs: the cache write
+    with jax.named_scope("kv_write"):
+        (x, aux), caches = jax.lax.scan(
+            layer_fn, (x, jnp.zeros((), jnp.float32)), params["layers"])
+    with jax.named_scope("head"):
+        x = cm.rms_norm(x, params["ln_f"], cfg.norm_eps)
+        if return_hidden:
+            return x
+        head = cm.maybe_dequant(params["head"], compute_dtype)
+        logits = x.astype(compute_dtype) @ head
     aux = aux / cfg.n_layers
     if collect_cache:
         return logits, aux, {"k": caches[0], "v": caches[1]}
@@ -540,15 +546,18 @@ def paged_decode_step(params: dict, cache: dict, token: jax.Array,
     B = token.shape[0]
     _, P, page, row = cache["k"].shape
     M = block_tables.shape[1]
-    embed = cm.maybe_dequant(params["embed"], compute_dtype)
-    x = jnp.take(embed, token, axis=0)[:, None, :]               # (B, 1, d)
-    page_log = pos // page
-    phys = jnp.take_along_axis(
-        block_tables, jnp.minimum(page_log, M - 1)[:, None], axis=1)[:, 0]
-    flat = phys * page + pos % page
-    flat = jnp.where(page_log < M, flat, P * page)     # OOB write -> dropped
-    if write_mask is not None:
-        flat = jnp.where(write_mask, flat, P * page)
+    with jax.named_scope("embed"):
+        embed = cm.maybe_dequant(params["embed"], compute_dtype)
+        x = jnp.take(embed, token, axis=0)[:, None, :]           # (B, 1, d)
+    with jax.named_scope("kv_write"):
+        page_log = pos // page
+        phys = jnp.take_along_axis(
+            block_tables, jnp.minimum(page_log, M - 1)[:, None],
+            axis=1)[:, 0]
+        flat = phys * page + pos % page
+        flat = jnp.where(page_log < M, flat, P * page)  # OOB write -> dropped
+        if write_mask is not None:
+            flat = jnp.where(write_mask, flat, P * page)
     attn = attn_impl
     if attn is None:
         def attn(q, kp, vp, tables, cache_len):
@@ -561,30 +570,38 @@ def paged_decode_step(params: dict, cache: dict, token: jax.Array,
 
     def layer_fn(x, scanned):
         lp, kc, vc = scanned                           # (P, page, H_kv*D)
-        xn = cm.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q, k_new, v_new = _qkv(xn, lp, cfg, pos[:, None], compute_dtype)
-        kf = kc.astype(compute_dtype).reshape(P * page, row)
-        vf = vc.astype(compute_dtype).reshape(P * page, row)
-        kf = kf.at[flat].set(k_new.reshape(B, row), mode="drop")
-        vf = vf.at[flat].set(v_new.reshape(B, row), mode="drop")
-        kp = kf.reshape(P, page, row)
-        vp = vf.reshape(P, page, row)
-        out = attn(q, kp, vp, block_tables, pos + 1)
-        wo = cm.maybe_dequant(lp["wo"], compute_dtype)
-        x = x + (out.reshape(B, 1, cfg.n_heads * cfg.d_head)
-                 @ wo).astype(x.dtype)
-        xn = cm.rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.moe is not None:
-            h, _ = moe_ffn(xn, lp, cfg, compute_dtype)
-        else:
-            h = dense_ffn(xn, lp, compute_dtype, cfg.ffn_type)
-        return x + h, (kp, vp)
+        with jax.named_scope("attention"):
+            xn = cm.rms_norm(x, lp["ln1"], cfg.norm_eps)
+            q, k_new, v_new = _qkv(xn, lp, cfg, pos[:, None], compute_dtype)
+        with jax.named_scope("kv_write"):
+            kf = kc.astype(compute_dtype).reshape(P * page, row)
+            vf = vc.astype(compute_dtype).reshape(P * page, row)
+            kf = kf.at[flat].set(k_new.reshape(B, row), mode="drop")
+            vf = vf.at[flat].set(v_new.reshape(B, row), mode="drop")
+            kp = kf.reshape(P, page, row)
+            vp = vf.reshape(P, page, row)
+        with jax.named_scope("attention"):
+            out = attn(q, kp, vp, block_tables, pos + 1)
+            wo = cm.maybe_dequant(lp["wo"], compute_dtype)
+            x = x + (out.reshape(B, 1, cfg.n_heads * cfg.d_head)
+                     @ wo).astype(x.dtype)
+        with jax.named_scope("ffn"):
+            xn = cm.rms_norm(x, lp["ln2"], cfg.norm_eps)
+            if cfg.moe is not None:
+                h, _ = moe_ffn(xn, lp, cfg, compute_dtype)
+            else:
+                h = dense_ffn(xn, lp, compute_dtype, cfg.ffn_type)
+            x = x + h
+        return x, (kp, vp)
 
-    (x), caches = jax.lax.scan(
-        layer_fn, x, (params["layers"], cache["k"], cache["v"]))
-    x = cm.rms_norm(x, params["ln_f"], cfg.norm_eps)
-    head = cm.maybe_dequant(params["head"], compute_dtype)
-    logits = (x.astype(compute_dtype) @ head)[:, 0]              # (B, V)
+    # the scan's per-layer pool read and its write-back of the pool
+    with jax.named_scope("kv_write"):
+        (x), caches = jax.lax.scan(
+            layer_fn, x, (params["layers"], cache["k"], cache["v"]))
+    with jax.named_scope("head"):
+        x = cm.rms_norm(x, params["ln_f"], cfg.norm_eps)
+        head = cm.maybe_dequant(params["head"], compute_dtype)
+        logits = (x.astype(compute_dtype) @ head)[:, 0]          # (B, V)
     return logits, {"k": caches[0], "v": caches[1]}
 
 
@@ -611,53 +628,62 @@ def paged_chunk_extend(params: dict, cache: dict, block_row: jax.Array,
     M = block_row.shape[0]
     S = M * page
     T = tokens.shape[0]
-    embed = cm.maybe_dequant(params["embed"], compute_dtype)
-    x = jnp.take(embed, tokens, axis=0)[None]                 # (1, T, d)
+    with jax.named_scope("embed"):
+        embed = cm.maybe_dequant(params["embed"], compute_dtype)
+        x = jnp.take(embed, tokens, axis=0)[None]             # (1, T, d)
     offs = jnp.arange(T, dtype=jnp.int32)
     positions = (start_pos + offs)[None]                      # (1, T)
-    page_log = (start_pos + offs) // page
-    phys = block_row[jnp.minimum(page_log, M - 1)]
-    flat = phys * page + (start_pos + offs) % page
-    flat = jnp.where((offs < n_valid) & (page_log < M), flat, P * page)
+    with jax.named_scope("kv_write"):
+        page_log = (start_pos + offs) // page
+        phys = block_row[jnp.minimum(page_log, M - 1)]
+        flat = phys * page + (start_pos + offs) % page
+        flat = jnp.where((offs < n_valid) & (page_log < M), flat, P * page)
     scale = 1.0 / math.sqrt(cfg.d_head)
 
     def layer_fn(x, scanned):
         lp, kc, vc = scanned                           # (P, page, H_kv*D)
-        xn = cm.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q, k_new, v_new = _qkv(xn, lp, cfg, positions, compute_dtype)
-        kf = kc.astype(compute_dtype).reshape(P * page, row)
-        vf = vc.astype(compute_dtype).reshape(P * page, row)
-        kf = kf.at[flat].set(k_new[0].reshape(T, row), mode="drop")
-        vf = vf.at[flat].set(v_new[0].reshape(T, row), mode="drop")
-        kg = kf.reshape(P, page, row)[block_row]
-        vg = vf.reshape(P, page, row)[block_row]
-        kr = cm.repeat_kv(kg.reshape(1, S, cfg.n_kv_heads, cfg.d_head),
-                          cfg.q_per_kv)
-        vr = cm.repeat_kv(vg.reshape(1, S, cfg.n_kv_heads, cfg.d_head),
-                          cfg.q_per_kv)
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, kr).astype(
-            jnp.float32) * scale
-        mask = jnp.arange(S)[None, None, None, :] <= \
-            positions[0][None, None, :, None]
-        scores = jnp.where(mask, scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        out = jnp.einsum("bhqk,bkhd->bqhd", probs, vr)
-        wo = cm.maybe_dequant(lp["wo"], compute_dtype)
-        x = x + (out.reshape(1, T, cfg.n_heads * cfg.d_head)
-                 @ wo).astype(x.dtype)
-        xn = cm.rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.moe is not None:
-            h, _ = moe_ffn(xn, lp, cfg, compute_dtype)
-        else:
-            h = dense_ffn(xn, lp, compute_dtype, cfg.ffn_type)
-        return x + h, (kf.reshape(P, page, row), vf.reshape(P, page, row))
+        with jax.named_scope("attention"):
+            xn = cm.rms_norm(x, lp["ln1"], cfg.norm_eps)
+            q, k_new, v_new = _qkv(xn, lp, cfg, positions, compute_dtype)
+        with jax.named_scope("kv_write"):
+            kf = kc.astype(compute_dtype).reshape(P * page, row)
+            vf = vc.astype(compute_dtype).reshape(P * page, row)
+            kf = kf.at[flat].set(k_new[0].reshape(T, row), mode="drop")
+            vf = vf.at[flat].set(v_new[0].reshape(T, row), mode="drop")
+        with jax.named_scope("attention"):
+            kg = kf.reshape(P, page, row)[block_row]
+            vg = vf.reshape(P, page, row)[block_row]
+            kr = cm.repeat_kv(kg.reshape(1, S, cfg.n_kv_heads, cfg.d_head),
+                              cfg.q_per_kv)
+            vr = cm.repeat_kv(vg.reshape(1, S, cfg.n_kv_heads, cfg.d_head),
+                              cfg.q_per_kv)
+            scores = jnp.einsum("bqhd,bkhd->bhqk", q, kr).astype(
+                jnp.float32) * scale
+            mask = jnp.arange(S)[None, None, None, :] <= \
+                positions[0][None, None, :, None]
+            scores = jnp.where(mask, scores, -jnp.inf)
+            probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+            out = jnp.einsum("bhqk,bkhd->bqhd", probs, vr)
+            wo = cm.maybe_dequant(lp["wo"], compute_dtype)
+            x = x + (out.reshape(1, T, cfg.n_heads * cfg.d_head)
+                     @ wo).astype(x.dtype)
+        with jax.named_scope("ffn"):
+            xn = cm.rms_norm(x, lp["ln2"], cfg.norm_eps)
+            if cfg.moe is not None:
+                h, _ = moe_ffn(xn, lp, cfg, compute_dtype)
+            else:
+                h = dense_ffn(xn, lp, compute_dtype, cfg.ffn_type)
+            x = x + h
+        return x, (kf.reshape(P, page, row), vf.reshape(P, page, row))
 
-    (x), caches = jax.lax.scan(
-        layer_fn, x, (params["layers"], cache["k"], cache["v"]))
-    xf = cm.rms_norm(x, params["ln_f"], cfg.norm_eps)
-    head = cm.maybe_dequant(params["head"], compute_dtype)
-    last = xf[0, jnp.maximum(n_valid - 1, 0)]
-    logits = last.astype(compute_dtype) @ head                # (V,)
+    with jax.named_scope("kv_write"):
+        (x), caches = jax.lax.scan(
+            layer_fn, x, (params["layers"], cache["k"], cache["v"]))
+    with jax.named_scope("head"):
+        xf = cm.rms_norm(x, params["ln_f"], cfg.norm_eps)
+        head = cm.maybe_dequant(params["head"], compute_dtype)
+        last = xf[0, jnp.maximum(n_valid - 1, 0)]
+        logits = last.astype(compute_dtype) @ head            # (V,)
     return {"k": caches[0], "v": caches[1]}, logits
 
 

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models.common import named
 from repro.retrieval import kmeans as km
 
 
@@ -100,37 +101,42 @@ def pq_scan_ref(tables: jax.Array, codes: jax.Array) -> jax.Array:
     return gathered.sum(axis=-1)
 
 
-@partial(jax.jit, static_argnames=("nprobe", "k", "use_kernel"))
-def search(index: IVFPQIndex, queries: jax.Array, nprobe: int = 8,
-           k: int = 10, use_kernel: bool = False):
+def _search(index: IVFPQIndex, queries: jax.Array, nprobe: int = 8,
+            k: int = 10, use_kernel: bool = False):
     """Returns (distances (Q, k), ids (Q, k)).  Fully static shapes."""
     # 1) coarse scan
-    c2 = jnp.sum(index.centroids ** 2, axis=-1)
-    coarse = c2[None] - 2.0 * queries @ index.centroids.T      # (Q, L)
-    _, probe = jax.lax.top_k(-coarse, nprobe)                  # (Q, P)
-    probe_centroids = jnp.take(index.centroids, probe, axis=0)
+    with jax.named_scope("coarse"):
+        c2 = jnp.sum(index.centroids ** 2, axis=-1)
+        coarse = c2[None] - 2.0 * queries @ index.centroids.T  # (Q, L)
+        _, probe = jax.lax.top_k(-coarse, nprobe)              # (Q, P)
+        probe_centroids = jnp.take(index.centroids, probe, axis=0)
 
-    # 2) ADC tables
-    tables = adc_tables(index, queries, probe_centroids)       # (Q,P,S,256)
-
-    # 3) PQ scan over probed lists
-    codes = jnp.take(index.list_codes, probe, axis=0)          # (Q,P,LL,S)
-    ids = jnp.take(index.list_ids, probe, axis=0)              # (Q,P,LL)
-    if use_kernel:
-        from repro.kernels.pq_scan.ops import pq_scan
-        q, p, ll, s = codes.shape
-        dists = pq_scan(tables.reshape(q * p, s, 256),
-                        codes.reshape(q * p, ll, s)).reshape(q, p, ll)
-    else:
-        dists = pq_scan_ref(tables, codes)                     # (Q,P,LL)
-    dists = jnp.where(ids >= 0, dists, jnp.inf)
+    with jax.named_scope("adc"):
+        # 2) ADC tables
+        tables = adc_tables(index, queries, probe_centroids)   # (Q,P,S,256)
+        # 3) PQ scan over probed lists
+        codes = jnp.take(index.list_codes, probe, axis=0)      # (Q,P,LL,S)
+        ids = jnp.take(index.list_ids, probe, axis=0)          # (Q,P,LL)
+        if use_kernel:
+            from repro.kernels.pq_scan.ops import pq_scan
+            q, p, ll, s = codes.shape
+            dists = pq_scan(tables.reshape(q * p, s, 256),
+                            codes.reshape(q * p, ll, s)).reshape(q, p, ll)
+        else:
+            dists = pq_scan_ref(tables, codes)                 # (Q,P,LL)
+        dists = jnp.where(ids >= 0, dists, jnp.inf)
 
     # 4) top-k across all probed lists
-    qn = queries.shape[0]
-    flat_d = dists.reshape(qn, -1)
-    flat_i = ids.reshape(qn, -1)
-    neg, pos = jax.lax.top_k(-flat_d, k)
-    return -neg, jnp.take_along_axis(flat_i, pos, axis=1)
+    with jax.named_scope("topk"):
+        qn = queries.shape[0]
+        flat_d = dists.reshape(qn, -1)
+        flat_i = ids.reshape(qn, -1)
+        neg, pos = jax.lax.top_k(-flat_d, k)
+        return -neg, jnp.take_along_axis(flat_i, pos, axis=1)
+
+
+search = jax.jit(named("rago_search", _search),
+                 static_argnames=("nprobe", "k", "use_kernel"))
 
 
 def overlap_recall(approx_ids, exact_ids) -> float:

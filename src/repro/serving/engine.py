@@ -69,13 +69,14 @@ import numpy as np
 
 from repro.core.stage_registry import REGISTRY
 from repro.models import transformer as tr
+from repro.models.common import named
 from repro.retrieval.backend import (ExactBackend, FallbackBackend,
                                      make_backend)
 from repro.serving.faults import EngineCrash, EngineHealth
 from repro.serving.kv_cache import KVCachePool, PagedKVCachePool
 from repro.serving.request import Request, State
-from repro.serving.telemetry import (NULL_TRACER, MetricsRegistry,
-                                     stage_kind)
+from repro.serving.telemetry import (NULL_TRACER, PROFILER_STAGE_NAMES,
+                                     MetricsRegistry, stage_kind)
 
 
 def bucket_len(n: int, floor: int = 8) -> int:
@@ -239,11 +240,14 @@ class RAGEngine:
         self._fused_decode_jit = jax.jit(
             partial(self._fused_decode, cfg=self.gen.cfg, attn=dense_attn),
             donate_argnums=(1,))
+        # the serving path's programs carry fixed names (jit_rago_*) on
+        # the device trace
         self._paged_decode_jit = jax.jit(
-            partial(self._paged_fused_decode, cfg=self.gen.cfg,
-                    attn=paged_attn),
+            named("rago_decode", partial(self._paged_fused_decode,
+                                         cfg=self.gen.cfg, attn=paged_attn)),
             donate_argnums=(1,))
-        self._encode_jit = jax.jit(partial(tr.encode, cfg=self.enc.cfg))
+        self._encode_jit = jax.jit(
+            named("rago_encode", partial(tr.encode, cfg=self.enc.cfg)))
         self._prefill_jit = {}                   # bucket -> jitted prefill
         self._append_jit = {}                    # bucket -> jitted extend
         # database embeddings (the paper's offline encode step)
@@ -388,8 +392,9 @@ class RAGEngine:
 
     @contextmanager
     def _timed(self, stage: str, req: Request | None = None, attrs=None):
-        """Accumulate wall time into ``metrics['stage_time_s'][stage]``, a
-        per-stage latency histogram, and (when tracing) a span.
+        """Accumulate wall time into ``metrics['stage_time_s'][stage]`` and
+        (when tracing) record a span, and a profiler span for the stages in
+        ``PROFILER_STAGE_NAMES``.
 
         Attribution is wall-clock at the call site: executor stages are
         timed inclusively (their internal ``embed``/``retrieve`` primitive
@@ -405,6 +410,8 @@ class RAGEngine:
         t0 = time.monotonic()
         tracer = self.tracer
         span = None
+        profiled = tracer.profiler_span(PROFILER_STAGE_NAMES.get(stage),
+                                        self.tick_no, t0)
         if tracer.enabled and req is not None:
             span = tracer.begin(stage_kind(stage), rid=req.rid,
                                 engine=self.trace_name, t=t0,
@@ -412,12 +419,12 @@ class RAGEngine:
                                 attempt=req.retries + req.migrations,
                                 attrs=attrs)
         try:
-            yield
+            with profiled:
+                yield
         finally:
             t1 = time.monotonic()
             acc = self.metrics["stage_time_s"]
             acc[stage] = acc.get(stage, 0.0) + t1 - t0
-            self.metrics.observe("stage_seconds:" + stage, t1 - t0)
             if span is not None:
                 tracer.end(span, t=t1)
             elif tracer.enabled:
@@ -497,8 +504,8 @@ class RAGEngine:
         bucket = bucket_len(length)
         fn = self._prefill_jit.get(bucket)
         if fn is None:
-            fn = jax.jit(partial(tr.forward, cfg=self.gen.cfg,
-                                 collect_cache=True))
+            fn = jax.jit(named("rago_prefill", partial(
+                tr.forward, cfg=self.gen.cfg, collect_cache=True)))
             self._prefill_jit[bucket] = fn
             self.metrics["prefill_compiles"] += 1
         padded = np.zeros((1, bucket), np.int32)
@@ -524,33 +531,37 @@ class RAGEngine:
         while self.queue and self.pool.free:
             req = self.queue.pop(0)
             tracer = self.tracer
+            with tracer.profiler_span("rago.admit", self.tick_no):
+                self._admit_one(req, tracer)
+
+    def _admit_one(self, req: Request, tracer) -> None:
+        if tracer.enabled:
+            tracer.event("ADMIT", rid=req.rid, engine=self.trace_name,
+                         tick=self.tick_no,
+                         attempt=req.retries + req.migrations)
+        for ex in self.executors:
+            with self._timed(ex.name, req=req):
+                ex.run(self, req)
+        req.prompt = self._assemble_prompt(req)
+        slot = self.pool.alloc(req.rid)
+        if self.cfg.prefill_chunk:
+            # continuous batching: the slot enters PREFILL and the prompt
+            # streams in chunk-by-chunk across decode ticks (_prefill_tick)
+            # instead of monopolizing the engine
+            req.state = State.PREFILL
+            req.slot = slot
+            self.prefilling[slot] = 0
+            self.active[slot] = req
+        else:
+            with self._timed("prefill", req=req):
+                self._prefill(req, slot)
+            self.active[req.slot] = req
             if tracer.enabled:
-                tracer.event("ADMIT", rid=req.rid, engine=self.trace_name,
-                             tick=self.tick_no,
-                             attempt=req.retries + req.migrations)
-            for ex in self.executors:
-                with self._timed(ex.name, req=req):
-                    ex.run(self, req)
-            req.prompt = self._assemble_prompt(req)
-            slot = self.pool.alloc(req.rid)
-            if self.cfg.prefill_chunk:
-                # continuous batching: the slot enters PREFILL and the
-                # prompt streams in chunk-by-chunk across decode ticks
-                # (_prefill_tick) instead of monopolizing the engine
-                req.state = State.PREFILL
-                req.slot = slot
-                self.prefilling[slot] = 0
-                self.active[slot] = req
-            else:
-                with self._timed("prefill", req=req):
-                    self._prefill(req, slot)
-                self.active[req.slot] = req
-                if tracer.enabled:
-                    # decode-slot residency: open until DONE/retry closes it
-                    tracer.begin("DECODE", rid=req.rid,
-                                 engine=self.trace_name, tick=self.tick_no,
-                                 attempt=req.retries + req.migrations,
-                                 attrs={"slot": req.slot})
+                # decode-slot residency: open until DONE/retry closes it
+                tracer.begin("DECODE", rid=req.rid,
+                             engine=self.trace_name, tick=self.tick_no,
+                             attempt=req.retries + req.migrations,
+                             attrs={"slot": req.slot})
 
     def _prefill_tick(self) -> None:
         """Advance every chunk-prefilling slot by one prompt chunk.  The
@@ -643,8 +654,9 @@ class RAGEngine:
         bucket = bucket_len(t)
         fn = self._append_jit.get(bucket)
         if fn is None:
-            fn = jax.jit(partial(tr.paged_chunk_extend, cfg=self.gen.cfg),
-                         donate_argnums=(1,))
+            fn = jax.jit(named("rago_chunk_extend", partial(
+                tr.paged_chunk_extend, cfg=self.gen.cfg)),
+                donate_argnums=(1,))
             self._append_jit[bucket] = fn
             self.metrics["append_compiles"] += 1
         padded = np.zeros(bucket, np.int32)
@@ -741,8 +753,9 @@ class RAGEngine:
         logits, cache = tr.paged_decode_step(
             params, cache, token_vec, positions, block_tables, cfg,
             attn_impl=attn, write_mask=step_mask)
-        tokens = jnp.argmax(logits[:, :cfg.vocab_size], axis=-1)
-        return tokens.astype(jnp.int32), cache
+        with jax.named_scope("head"):
+            tokens = jnp.argmax(logits[:, :cfg.vocab_size], axis=-1)
+            return tokens.astype(jnp.int32), cache
 
     def _decode_step(self) -> None:
         token_vec = np.zeros(self.pool.n_slots, np.int32)
@@ -773,16 +786,23 @@ class RAGEngine:
             self._decode_active(token_vec, stepping)
 
     def _decode_active(self, token_vec, stepping) -> None:
+        # profiler sub-spans of rago.decode (no-ops with tracing off)
+        span, tick = self.tracer.profiler_span, self.tick_no
         if isinstance(self.pool, PagedKVCachePool):
-            for slot in stepping:        # allocate/COW each write target
-                self.pool.prepare_append(slot, 1)
-            step_mask = np.zeros(self.pool.n_slots, bool)
-            step_mask[stepping] = True
-            toks, self.pool.cache = self._paged_decode_jit(
-                self.gen.params, self.pool.cache, jnp.asarray(token_vec),
-                self.pool.positions(), jnp.asarray(self.pool.block_tables()),
-                jnp.asarray(step_mask))
-            new_tokens = np.asarray(toks)            # the step's one sync
+            with span("rago.decode.prepare", tick):
+                for slot in stepping:    # allocate/COW each write target
+                    self.pool.prepare_append(slot, 1)
+                step_mask = np.zeros(self.pool.n_slots, bool)
+                step_mask[stepping] = True
+                positions = self.pool.positions()
+                tables = jnp.asarray(self.pool.block_tables())
+            with span("rago.decode.launch", tick):
+                toks, self.pool.cache = self._paged_decode_jit(
+                    self.gen.params, self.pool.cache,
+                    jnp.asarray(token_vec), positions, tables,
+                    jnp.asarray(step_mask))
+            with span("rago.decode.fetch", tick):
+                new_tokens = np.asarray(toks)        # the step's one sync
         elif self.cfg.fused_decode:
             step_mask = np.zeros(self.pool.n_slots, bool)
             step_mask[stepping] = True
@@ -805,6 +825,11 @@ class RAGEngine:
                 cache, self.pool.cache)
             self.metrics["cache_copy_bytes"] += sum(
                 v.nbytes for v in self.pool.cache.values())
+        with span("rago.decode.commit", tick):
+            self._commit_tokens(new_tokens, stepping)
+
+    def _commit_tokens(self, new_tokens, stepping) -> None:
+        """Append the step's tokens; release the slots that finished."""
         self.metrics["host_syncs"] += 1
         self.metrics["decode_host_syncs"] += 1
         self.pool.advance(stepping)

@@ -39,10 +39,30 @@ from collections import OrderedDict
 from dataclasses import dataclass
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.models import transformer as tr
+from repro.models.common import named
+
+
+def _install_pages(cache: dict, layer_cache: dict, log_idx, phys_idx, *,
+                   length: int) -> dict:
+    """One scatter installs a prefill cache's (L, 1, S, H, D) first
+    ``length`` positions, cut into pages, at logical pages ``log_idx`` into
+    physical pages ``phys_idx`` of the pool."""
+    L, _, ps, row = cache["k"].shape
+    n_pages = -(-length // ps)
+    pad = n_pages * ps - length
+    return {k: cache[k].at[:, phys_idx].set(
+                jnp.pad(v[:, 0, :length], ((0, 0), (0, pad), (0, 0), (0, 0)))
+                .reshape(L, n_pages, ps, row)[:, log_idx])
+            for k, v in layer_cache.items()}
+
+
+_install_pages = jax.jit(named("rago_page_install", _install_pages),
+                         static_argnames=("length",))
 
 
 class ImportStats(NamedTuple):
@@ -358,17 +378,9 @@ class PagedKVCachePool:
                     self._register(phys, key)
         self.page_tables[slot] = table
         if fresh:
-            # one scatter installs every freshly written page
-            pad = n_pages * ps - p
-            log_idx = np.asarray([j for j, _ in fresh])
-            phys_idx = np.asarray([q for _, q in fresh])
-            L = self.cfg.n_layers
-            row = self.cfg.n_kv_heads * self.cfg.d_head
-            self.cache = {
-                k: self.cache[k].at[:, phys_idx].set(
-                    jnp.pad(v[:, 0, :p], ((0, 0), (0, pad), (0, 0), (0, 0)))
-                    .reshape(L, n_pages, ps, row)[:, log_idx])
-                for k, v in layer_cache.items()}
+            self.cache = _install_pages(
+                self.cache, layer_cache, np.asarray([j for j, _ in fresh]),
+                np.asarray([q for _, q in fresh]), length=p)
         self.lengths[slot] = p
 
     def export_slot(self, slot: int) -> tuple[PagedPrefix, int]:

@@ -292,14 +292,16 @@ class RAGServer:
         queue[:] = keep
 
     def _deliver(self) -> None:
-        still = []
-        for h in self._live:
-            h._deliver()
-            if h.done:
-                self._observe_terminal(h.request)
-            else:
-                still.append(h)
-        self._live = still
+        tick = self.engine.tick_no if self.engine is not None else 0
+        with self.tracer.profiler_span("rago.deliver", tick):
+            still = []
+            for h in self._live:
+                h._deliver()             # fires the token callbacks
+                if h.done:
+                    self._observe_terminal(h.request)
+                else:
+                    still.append(h)
+            self._live = still
 
     def _observe_terminal(self, req: Request) -> None:
         """Feed the server-level latency histograms as a request leaves

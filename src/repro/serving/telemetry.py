@@ -15,6 +15,14 @@ decode).  This module is the substrate:
   **bounded-memory when on** (:class:`SpanTracer` keeps a ring buffer and
   counts overwritten spans in ``dropped``).
 
+* **Profiler spans** -- with a :class:`SpanTracer` installed, the engine's
+  stages and decode sub-steps and the server's token delivery also open a
+  ``jax.profiler.TraceAnnotation`` with a fixed ``rago.*`` name
+  (:meth:`SpanTracer.profiler_span`), so a ``jax.profiler`` trace shows
+  them beside the device's programs.  Each carries the engine tick and
+  its ``time.monotonic`` start in ns, which puts this tracer's spans on
+  the profiler's clock.
+
 * **Metrics registry** -- :class:`MetricsRegistry` replaces the free-form
   ``self.metrics`` dicts in the engine/cluster.  It is a
   ``MutableMapping`` so existing ``metrics["x"] += 1`` call sites keep
@@ -63,6 +71,17 @@ STAGE_SPAN_BUCKETS = {
     "PREFILL_CHUNK": "prefill",
     "HANDOFF": "handoff",
     "DECODE": "decode",
+}
+
+
+#: The profiler's names for the engine's ``_timed`` stages (other stages put
+#: no span on the profiler's trace).  Names are fixed: no line numbers, no
+#: per-call text, so two runs' traces compare name for name.
+PROFILER_STAGE_NAMES = {
+    "embed": "rago.embed",
+    "retrieve": "rago.retrieve",
+    "prefill": "rago.prefill",
+    "decode": "rago.decode",
 }
 
 
@@ -163,6 +182,9 @@ class NullTracer:
 
     def open_spans(self):
         return {}
+
+    def profiler_span(self, name, tick=0, t0=None):
+        return _NULL_CTX
 
 
 #: Shared no-op tracer. Engines/clusters/servers default to this.
@@ -273,6 +295,19 @@ class SpanTracer:
         t = MONO() if t is None else t
         self.close_open(rid, t=t, outcome=state)
         self.event("TERMINAL", rid=rid, t=t, attrs={"state": state})
+
+    def profiler_span(self, name, tick=0, t0=None):
+        """A ``jax.profiler.TraceAnnotation`` named ``name`` (``None``: a
+        no-op context), with ``tick`` and the span's ``time.monotonic``
+        start in ns (``t0`` seconds, default now) as its metadata.  Inside a
+        ``jax.profiler`` trace it is a host event on the profiler's clock;
+        ``start_ns - mono_ns`` maps this tracer's spans onto that clock.
+        Outside one it records nothing."""
+        if name is None:
+            return _NULL_CTX
+        from jax.profiler import TraceAnnotation
+        mono_ns = time.monotonic_ns() if t0 is None else int(t0 * 1e9)
+        return TraceAnnotation(name, tick=tick, mono_ns=mono_ns)
 
     # -- reading -----------------------------------------------------------
 

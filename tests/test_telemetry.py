@@ -422,15 +422,21 @@ def test_decode_crash_retry_attempts_are_disjoint(stack):
 @pytest.mark.slow
 def test_tracing_off_constructs_no_spans(stack, monkeypatch):
     """Zero-cost-when-off: with the default ``NULL_TRACER`` the serving
-    path must never construct a Span (patching the constructor to raise
-    proves it is never reached), and the metrics snapshot must be fully
-    detached from the live registry."""
+    path must never construct a Span or a profiler ``TraceAnnotation``
+    (patching both constructors to raise proves neither is reached), and
+    the metrics snapshot must be fully detached from the live registry."""
+    import jax.profiler
+
     from repro.serving.engine import EngineConfig, RAGEngine
 
     def boom(*a, **kw):
         raise AssertionError("Span constructed with tracing off")
 
+    def boom_annotation(*a, **kw):
+        raise AssertionError("TraceAnnotation constructed with tracing off")
+
     monkeypatch.setattr(T, "Span", boom)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom_annotation)
     gen, enc, corpus, questions = stack
     eng = RAGEngine(gen, enc, corpus,
                     EngineConfig(decode_slots=2, s_max=96,
