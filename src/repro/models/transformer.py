@@ -542,9 +542,13 @@ def paged_decode_step(params: dict, cache: dict, token: jax.Array,
     (B, M*page, H, D) view, repeat KV heads, reference masked softmax.
     Non-stepping rows read the same pool bytes either way (their write
     was dropped), so every impl sees identical inputs under a mask.
+
+    The pool is carried through the layer scan and written in place, one
+    row per stepping slot and layer; it keeps its own dtype (the new K/V
+    is cast to it, the layer's pages to ``compute_dtype`` for attention).
     """
     B = token.shape[0]
-    _, P, page, row = cache["k"].shape
+    L, P, page, row = cache["k"].shape
     M = block_tables.shape[1]
     with jax.named_scope("embed"):
         embed = cm.maybe_dequant(params["embed"], compute_dtype)
@@ -558,6 +562,7 @@ def paged_decode_step(params: dict, cache: dict, token: jax.Array,
         flat = jnp.where(page_log < M, flat, P * page)  # OOB write -> dropped
         if write_mask is not None:
             flat = jnp.where(write_mask, flat, P * page)
+        page_idx, page_row = flat // page, flat % page  # dropped: page P
     attn = attn_impl
     if attn is None:
         def attn(q, kp, vp, tables, cache_len):
@@ -568,18 +573,21 @@ def paged_decode_step(params: dict, cache: dict, token: jax.Array,
             vr = cm.repeat_kv(vg, cfg.q_per_kv)
             return cm.decode_attention_ref(q, kr, vr, cache_len)
 
-    def layer_fn(x, scanned):
-        lp, kc, vc = scanned                           # (P, page, H_kv*D)
+    def layer_fn(carry, scanned):
+        x, k_pool, v_pool = carry                  # pools: (L, P, page, row)
+        lp, l = scanned
         with jax.named_scope("attention"):
             xn = cm.rms_norm(x, lp["ln1"], cfg.norm_eps)
             q, k_new, v_new = _qkv(xn, lp, cfg, pos[:, None], compute_dtype)
         with jax.named_scope("kv_write"):
-            kf = kc.astype(compute_dtype).reshape(P * page, row)
-            vf = vc.astype(compute_dtype).reshape(P * page, row)
-            kf = kf.at[flat].set(k_new.reshape(B, row), mode="drop")
-            vf = vf.at[flat].set(v_new.reshape(B, row), mode="drop")
-            kp = kf.reshape(P, page, row)
-            vp = vf.reshape(P, page, row)
+            k_pool = k_pool.at[l, page_idx, page_row].set(
+                k_new.reshape(B, row).astype(k_pool.dtype), mode="drop")
+            v_pool = v_pool.at[l, page_idx, page_row].set(
+                v_new.reshape(B, row).astype(v_pool.dtype), mode="drop")
+            kp = jax.lax.dynamic_index_in_dim(
+                k_pool, l, 0, keepdims=False).astype(compute_dtype)
+            vp = jax.lax.dynamic_index_in_dim(
+                v_pool, l, 0, keepdims=False).astype(compute_dtype)
         with jax.named_scope("attention"):
             out = attn(q, kp, vp, block_tables, pos + 1)
             wo = cm.maybe_dequant(lp["wo"], compute_dtype)
@@ -592,17 +600,20 @@ def paged_decode_step(params: dict, cache: dict, token: jax.Array,
             else:
                 h = dense_ffn(xn, lp, compute_dtype, cfg.ffn_type)
             x = x + h
-        return x, (kp, vp)
+        return (x, k_pool, v_pool), None
 
-    # the scan's per-layer pool read and its write-back of the pool
+    # the pools ride in the carry, not in xs/ys: stacked ys make XLA build
+    # a second pool and copy it into the donated output on every step.
+    # Under this scope stay the scan's slices of the layer weights.
     with jax.named_scope("kv_write"):
-        (x), caches = jax.lax.scan(
-            layer_fn, x, (params["layers"], cache["k"], cache["v"]))
+        (x, k_pool, v_pool), _ = jax.lax.scan(
+            layer_fn, (x, cache["k"], cache["v"]),
+            (params["layers"], jnp.arange(L, dtype=jnp.int32)))
     with jax.named_scope("head"):
         x = cm.rms_norm(x, params["ln_f"], cfg.norm_eps)
         head = cm.maybe_dequant(params["head"], compute_dtype)
         logits = (x.astype(compute_dtype) @ head)[:, 0]          # (B, V)
-    return logits, {"k": caches[0], "v": caches[1]}
+    return logits, {"k": k_pool, "v": v_pool}
 
 
 def paged_chunk_extend(params: dict, cache: dict, block_row: jax.Array,

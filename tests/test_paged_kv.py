@@ -7,6 +7,7 @@ Tier structure mirrors test_cluster: pool-level tests fabricate K/V and
 are fast; anything that builds a RAGEngine is ``slow``.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -251,6 +252,50 @@ def test_pool_capacity_invariants():
     dense.write_prefix(d, _rand_cache(cfg, 16, seed=11), 16)
     with pytest.raises(AssertionError, match="s_max"):
         dense.advance([d])
+
+
+# ---------------------------------------------------------------------------
+# The decode step's in-place layer write (fast)
+# ---------------------------------------------------------------------------
+
+def test_paged_decode_step_writes_only_stepping_rows():
+    """One ``paged_decode_step`` over a float32 pool writes each stepping
+    slot's row at ``pos`` in every layer and nothing else: masked slots
+    and a position past the block table are dropped, and the pool keeps
+    its dtype.  The written K/V is the dense ``decode_step``'s on the same
+    logical cache (the "ref" attention reads the same bytes)."""
+    cfg = _tiny_cfg()
+    params = tr.init_params(jax.random.PRNGKey(0), cfg)
+    page, m, b = 4, 4, 4
+    row = cfg.n_kv_heads * cfg.d_head
+    rng = np.random.default_rng(3)
+    pool = {k: jnp.asarray(rng.standard_normal(
+        (cfg.n_layers, b * m + 2, page, row)), jnp.float32)
+        for k in ("k", "v")}
+    tables = rng.permutation(b * m + 2)[:b * m].reshape(b, m)
+    token = jnp.asarray([3, 5, 7, 9], jnp.int32)
+    # slot 1 is masked; slot 3 steps at the first position past its table
+    pos = np.array([6, 2, 12, m * page])
+    mask = np.array([True, False, True, True])
+    _, out = tr.paged_decode_step(params, pool, token, jnp.asarray(pos),
+                                  jnp.asarray(tables, jnp.int32), cfg,
+                                  write_mask=jnp.asarray(mask))
+    # the same logical cache, dense: (L, B, m*page, H_kv, D)
+    dense = {k: v[:, tables].reshape(cfg.n_layers, b, m * page,
+                                     cfg.n_kv_heads, cfg.d_head)
+             for k, v in pool.items()}
+    _, dense_out = tr.decode_step(params, dense, token,
+                                  jnp.asarray(np.minimum(pos, m * page - 1)),
+                                  cfg)
+    for k in ("k", "v"):
+        assert out[k].dtype == jnp.float32
+        want = np.asarray(pool[k]).copy()
+        for s in np.flatnonzero(mask & (pos < m * page)):
+            phys = tables[s, pos[s] // page]
+            want[:, phys, pos[s] % page] = np.asarray(
+                dense_out[k][:, s, pos[s]], np.float32).reshape(
+                    cfg.n_layers, row)
+        assert np.array_equal(np.asarray(out[k]), want)
 
 
 def test_engine_config_validation():

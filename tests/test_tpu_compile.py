@@ -6,7 +6,9 @@ refuse (tile-misaligned kernel slices, VMEM overruns, programs that do not
 fit the device).  Interpret mode catches none of that.  These tests compile
 the serving main path at granite-3-2b's full widths: the paged-decode
 kernel, the IVF-PQ scan at the smoke run's shape, and the bucketed prefill,
-paged fused decode step and chunk-extend programs with bf16 parameters.
+paged fused decode step and chunk-extend programs with bf16 parameters;
+and the decode step at the benchmark cell's shapes, which must update the
+donated page pool in place.
 
 The topology is described inside a module-scoped fixture (never at import:
 only one process at a time may load the TPU library), and the persistent
@@ -14,7 +16,9 @@ compilation cache is off around the compiles -- an entry written for a
 described chip cannot be read back without one.
 """
 
+import math
 import os
+import re
 import sys
 from functools import partial
 from pathlib import Path
@@ -129,8 +133,8 @@ def granite_params(one_chip):
     return _spec(tr.abstract_params(GRANITE, jnp.bfloat16), one_chip)
 
 
-def _paged_pool(sharding):
-    n_pages = SLOTS * (S_MAX // PAGE) + SPARE
+def _paged_pool(sharding, slots=SLOTS, s_max=S_MAX):
+    n_pages = slots * (s_max // PAGE) + SPARE
     shape = (GRANITE.n_layers, n_pages, PAGE,
              GRANITE.n_kv_heads * GRANITE.d_head)
     return {k: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
@@ -144,17 +148,44 @@ def test_granite_prefill_compiles(one_chip, granite_params):
     _fits(f.lower(granite_params, tokens).compile())
 
 
-def test_granite_paged_decode_step_compiles(one_chip, granite_params):
+def _compile_paged_decode(one_chip, params, pool, slots, s_max):
+    """The engine's donated fused decode step with the Pallas kernel."""
     f = jax.jit(partial(RAGEngine._paged_fused_decode, cfg=GRANITE,
                         attn=_pallas_attn), donate_argnums=(1,))
-    vec = _spec(jax.ShapeDtypeStruct((SLOTS,), jnp.int32), one_chip)
-    compiled = f.lower(
-        granite_params, _paged_pool(one_chip), vec, vec,
-        _spec(jax.ShapeDtypeStruct((SLOTS, S_MAX // PAGE), jnp.int32),
+    vec = _spec(jax.ShapeDtypeStruct((slots,), jnp.int32), one_chip)
+    return f.lower(
+        params, pool, vec, vec,
+        _spec(jax.ShapeDtypeStruct((slots, s_max // PAGE), jnp.int32),
               one_chip),
-        _spec(jax.ShapeDtypeStruct((SLOTS,), jnp.bool_), one_chip)).compile()
+        _spec(jax.ShapeDtypeStruct((slots,), jnp.bool_), one_chip)).compile()
+
+
+def test_granite_paged_decode_step_compiles(one_chip, granite_params):
+    compiled = _compile_paged_decode(one_chip, granite_params,
+                                     _paged_pool(one_chip), SLOTS, S_MAX)
     assert "tpu_custom_call" in compiled.as_text()
     _fits(compiled)
+
+
+def test_granite_paged_decode_step_updates_pool_in_place(one_chip,
+                                                         granite_params):
+    """At the ``granite-3-2b.rag-decode`` benchmark cell's shapes (32
+    slots, s_max 768, 16 spare pages: 1552 pages) the donated pool is
+    written in place: the step needs less scratch memory than one layer's
+    K and V pages, and copies neither whole pool (a pool passed through
+    the layer scan as xs/ys costs a second pool and a copy of each)."""
+    slots, s_max = 32, 768
+    pool = _paged_pool(one_chip, slots, s_max)
+    compiled = _compile_paged_decode(one_chip, granite_params, pool,
+                                     slots, s_max)
+    shape = pool["k"].shape
+    layer_bytes = 2 * math.prod(shape[1:]) * pool["k"].dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    whole_pool = re.compile(
+        r"= bf16\[%s\]\{[^}]*\} copy\(" % ",".join(map(str, shape)))
+    copies = [ln for ln in compiled.as_text().splitlines()
+              if whole_pool.search(ln)]
+    assert not copies, copies
 
 
 def test_granite_paged_chunk_extend_compiles(one_chip, granite_params):
