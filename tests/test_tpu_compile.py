@@ -7,8 +7,8 @@ fit the device).  Interpret mode catches none of that.  These tests compile
 the serving main path at granite-3-2b's full widths: the paged-decode
 kernel, the IVF-PQ scan at the smoke run's shape, and the bucketed prefill,
 paged fused decode step and chunk-extend programs with bf16 parameters;
-and the decode step at the benchmark cell's shapes, which must update the
-donated page pool in place.
+and the decode step at the benchmark cells' shapes (granite-3-2b and
+chatglm3-6b), which must update the donated page pool in place.
 
 The topology is described inside a module-scoped fixture (never at import:
 only one process at a time may load the TPU library), and the persistent
@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs import granite_3_2b
+from repro.configs import chatglm3_6b, granite_3_2b
 from repro.kernels.paged_attention.ops import paged_decode_attention
 from repro.kernels.pq_scan.pq_scan import pq_scan_pallas
 from repro.models import transformer as tr
@@ -42,6 +42,7 @@ finally:
 
 HBM_BYTES = 16 * 2**30                 # one TPU v5e chip
 GRANITE = granite_3_2b.CONFIG
+CHATGLM = chatglm3_6b.CONFIG
 # the chip smoke run's serving shapes
 SIZES = Sizes()
 SLOTS, S_MAX = SIZES.decode_slots, SIZES.s_max
@@ -133,10 +134,9 @@ def granite_params(one_chip):
     return _spec(tr.abstract_params(GRANITE, jnp.bfloat16), one_chip)
 
 
-def _paged_pool(sharding, slots=SLOTS, s_max=S_MAX):
+def _paged_pool(sharding, slots=SLOTS, s_max=S_MAX, cfg=GRANITE):
     n_pages = slots * (s_max // PAGE) + SPARE
-    shape = (GRANITE.n_layers, n_pages, PAGE,
-             GRANITE.n_kv_heads * GRANITE.d_head)
+    shape = (cfg.n_layers, n_pages, PAGE, cfg.n_kv_heads * cfg.d_head)
     return {k: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
             for k in ("k", "v")}
 
@@ -148,9 +148,10 @@ def test_granite_prefill_compiles(one_chip, granite_params):
     _fits(f.lower(granite_params, tokens).compile())
 
 
-def _compile_paged_decode(one_chip, params, pool, slots, s_max):
+def _compile_paged_decode(one_chip, params, pool, slots, s_max,
+                          cfg=GRANITE):
     """The engine's donated fused decode step with the Pallas kernel."""
-    f = jax.jit(partial(RAGEngine._paged_fused_decode, cfg=GRANITE,
+    f = jax.jit(partial(RAGEngine._paged_fused_decode, cfg=cfg,
                         attn=_pallas_attn), donate_argnums=(1,))
     vec = _spec(jax.ShapeDtypeStruct((slots,), jnp.int32), one_chip)
     return f.lower(
@@ -167,25 +168,56 @@ def test_granite_paged_decode_step_compiles(one_chip, granite_params):
     _fits(compiled)
 
 
-def test_granite_paged_decode_step_updates_pool_in_place(one_chip,
-                                                         granite_params):
-    """At the ``granite-3-2b.rag-decode`` benchmark cell's shapes (32
-    slots, s_max 768, 16 spare pages: 1552 pages) the donated pool is
-    written in place: the step needs less scratch memory than one layer's
-    K and V pages, and copies neither whole pool (a pool passed through
-    the layer scan as xs/ys costs a second pool and a copy of each)."""
+def _serving_extras(cfg) -> int:
+    """Bytes the benchmark's deployment keeps on the chip beside the
+    generator and its pool: the ENCODER_120M-shaped question encoder (bf16,
+    the generator's vocabulary, no head) and one chip's IVF-PQ shard
+    (2048 lists of 2048 vectors, 96 one-byte codes each, int32 ids,
+    float32 centroids and codebooks of 768 dims)."""
+    enc = tr.TransformerConfig(
+        name="encoder", n_layers=12, d_model=768, n_heads=12, n_kv_heads=12,
+        d_head=64, d_ff=3072, vocab_size=cfg.vocab_size, causal=False)
+    enc_params = tr.abstract_params(enc, jnp.bfloat16)
+    enc_params.pop("head")
+    n_vectors, n_lists, dim, n_subq = 2**22, 2048, 768, 96
+    index = n_vectors * (n_subq + 4) + 4 * (n_lists * dim + 256 * dim)
+    return _nbytes(enc_params) + index
+
+
+def _nbytes(tree) -> int:
+    return sum(a.size * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(tree))
+
+
+@pytest.mark.parametrize("cfg", [GRANITE, CHATGLM],
+                         ids=["granite-3-2b", "chatglm3-6b"])
+def test_paged_decode_step_updates_pool_in_place(one_chip, cfg):
+    """At the ``<model>.rag-decode`` benchmark cells' shapes (32 slots,
+    s_max 768, 16 spare pages: 1552 pages) the donated pool is written in
+    place: the step needs less scratch memory than one layer's K and V
+    pages, and neither copies nor dynamic-update-slices a whole pool (a
+    pool passed through the layer scan as xs/ys costs a second pool, its
+    write-back and a copy of each).  With the weights, the pool, the
+    encoder and the index resident, the step fits one chip."""
     slots, s_max = 32, 768
-    pool = _paged_pool(one_chip, slots, s_max)
-    compiled = _compile_paged_decode(one_chip, granite_params, pool,
-                                     slots, s_max)
+    params = _spec(tr.abstract_params(cfg, jnp.bfloat16), one_chip)
+    pool = _paged_pool(one_chip, slots, s_max, cfg)
+    compiled = _compile_paged_decode(one_chip, params, pool, slots, s_max,
+                                     cfg)
     shape = pool["k"].shape
     layer_bytes = 2 * math.prod(shape[1:]) * pool["k"].dtype.itemsize
-    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < layer_bytes
     whole_pool = re.compile(
-        r"= bf16\[%s\]\{[^}]*\} copy\(" % ",".join(map(str, shape)))
-    copies = [ln for ln in compiled.as_text().splitlines()
+        r"= bf16\[%s\]\{[^}]*\} (copy|dynamic-update-slice)\("
+        % ",".join(map(str, shape)))
+    writes = [ln for ln in compiled.as_text().splitlines()
               if whole_pool.search(ln)]
-    assert not copies, copies
+    assert not writes, writes
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes
+             + _serving_extras(cfg))
+    assert total < HBM_BYTES, (total, HBM_BYTES)
 
 
 def test_granite_paged_chunk_extend_compiles(one_chip, granite_params):
