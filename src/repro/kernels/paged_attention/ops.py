@@ -31,8 +31,9 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     """q: (B, 1, H, D) or (B, H, D); pages: (P, page, H_kv*D);
     block_tables: (B, M); lengths: (B,) -> same rank as q.
 
-    H query heads are grouped as (H_kv, q_per_kv) so each fetched KV page
-    serves all of a kv head's query heads -- KV is never repeated.
+    H query heads are grouped as (H_kv, q_per_kv): query head h*G + g
+    reads KV head h, and each fetched block of KV pages serves every query
+    head in one product -- KV is never repeated.
     """
     if interpret is None:
         interpret = _interpret_default()

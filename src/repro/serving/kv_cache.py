@@ -173,7 +173,9 @@ class KVCachePool:
         return int(sum(v.nbytes for v in prefix.values()))
 
     def positions(self) -> jnp.ndarray:
-        return jnp.asarray(self.lengths)
+        # a copy: on the CPU ``jnp.asarray`` shares the host buffer, and a
+        # dispatched step would see ``advance`` bump it under its feet
+        return jnp.array(self.lengths)
 
     def advance(self, slots: list[int]) -> None:
         for s in slots:
@@ -469,7 +471,9 @@ class PagedKVCachePool:
         return self.block_tables()[slot]
 
     def positions(self) -> jnp.ndarray:
-        return jnp.asarray(self.lengths)
+        # a copy: on the CPU ``jnp.asarray`` shares the host buffer, and a
+        # dispatched step would see ``advance`` bump it under its feet
+        return jnp.array(self.lengths)
 
     def advance(self, slots: list[int]) -> None:
         for s in slots:

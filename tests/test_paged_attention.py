@@ -6,7 +6,10 @@ dtype -- are gated BIT-EXACTLY against it; f32 runs compile with
 different fusion context and are gated at a few-ulp allclose.  Every
 configuration is additionally checked (allclose) against the dense
 semantic oracle, and the engine-facing tests hold every ``attn_impl``
-to the reference's decode logits along the reference trajectory.
+to the reference's decode logits along the reference trajectory.  The
+kernel walks blocks of pages, so lengths are checked at page and block
+edges, at both benchmark cells' head geometries, with pages outside the
+live range holding NaN.
 
 Tier structure: kernel-level tests run the interpret-mode kernel on tiny
 shapes and are fast; anything building a ``RAGEngine`` is ``slow``.
@@ -18,10 +21,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.paged_attention.ops import paged_decode_attention
 from repro.kernels.paged_attention.paged_attention import (
-    paged_decode_attention_pallas)
+    paged_decode_attention_pallas, pages_per_block)
 from repro.kernels.paged_attention.ref import (
     engine_ref_attn, paged_decode_attention_dense_ref,
     paged_decode_attention_ref, paged_gather)
@@ -139,9 +143,10 @@ def test_degenerate_page_geometry(page, m):
 
 
 def test_quad_buffering_bit_identical():
-    """Deeper DMA staging only changes prefetch distance, never values."""
-    q, k, v, tables, lens = _problem(3, 2, 2, 16, page=4, m_pages=8,
-                                     lengths=[0, 13, 32])
+    """Deeper DMA staging only changes prefetch distance, never values.
+    Five blocks of 8 pages, so quad buffering has three in flight."""
+    q, k, v, tables, lens = _problem(3, 2, 2, 16, page=16, m_pages=40,
+                                     lengths=[0, 300, 640])
     two = paged_decode_attention_pallas(q, k, v, tables, lens,
                                         num_buffers=2, interpret=True)
     four = paged_decode_attention_pallas(q, k, v, tables, lens,
@@ -149,6 +154,73 @@ def test_quad_buffering_bit_identical():
     assert np.array_equal(np.asarray(two, np.float32),
                           np.asarray(four, np.float32))
     _gate(q, k, v, tables, lens, num_buffers=4)
+
+
+# The cells' head geometries: granite-3-2b (8 KV heads of 64, 4 query
+# heads each) and chatglm3-6b (2 KV heads of 128, 16 query heads each).
+CELL_GEOMETRIES = pytest.mark.parametrize(
+    "h_kv,g,d", [(8, 4, 64), (2, 16, 128)], ids=["granite", "chatglm3"])
+# The serving page size with a table 1.5 blocks wide: the second block
+# has four slots past the table.
+CELL_PAGE, CELL_M = 16, 12
+
+
+@pytest.mark.parametrize("page,m,want", [
+    (16, 48, 8), (16, 12, 8), (1, 16, 16), (16, 1, 1), (8, 4, 4),
+    (48, 48, 3)])
+def test_pages_per_block_follows_page_and_table(page, m, want):
+    """The fewest pages covering 128 positions, capped at the table."""
+    assert pages_per_block(page, m) == want
+
+
+def _block_edge_lengths(page, m):
+    bk = pages_per_block(page, m) * page
+    assert m % (bk // page)                 # M is not a multiple of the block
+    return [0, 1, page - 1, page, bk - 1, bk, bk + 1, m * page]
+
+
+@CELL_GEOMETRIES
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_block_edges_at_cell_geometry(h_kv, g, d, dtype):
+    """Lengths at every page and block edge, up to a full table whose
+    last block runs past the table, at both cells' head geometries: the
+    mirror bit-exactly in bf16 and the dense oracle within tolerance."""
+    lengths = _block_edge_lengths(CELL_PAGE, CELL_M)
+    q, k, v, tables, lens = _problem(len(lengths), h_kv, g, d, CELL_PAGE,
+                                     CELL_M, lengths, dtype=dtype)
+    out = _gate(q, k, v, tables, lens)
+    assert not np.asarray(out[0], np.float32).any()
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+
+
+@CELL_GEOMETRIES
+def test_pages_past_live_range_are_never_read(h_kv, g, d):
+    """Every pool page outside a sequence's live pages -- the rest of its
+    table, and the spare page -- holds NaN, and the kernel runs on the
+    TPU interpreter, whose uninitialised VMEM reads NaN too.  The kernel
+    never fetches those pages and writes the last block's padding slots
+    as zero rows, so the output is finite and bit-identical to the run on
+    the clean pool, which the dense oracle checks."""
+    lengths = _block_edge_lengths(CELL_PAGE, CELL_M)
+    q, k, v, tables, lens = _problem(len(lengths), h_kv, g, d, CELL_PAGE,
+                                     CELL_M, lengths)
+    live = set()
+    for row, n in zip(np.asarray(tables), lengths):
+        live.update(row[:-(-n // CELL_PAGE)].tolist())
+    dead = np.array([p not in live for p in range(k.shape[0])])
+    assert dead.sum() > 0
+    nan_k = jnp.where(dead[:, None, None], jnp.nan, k).astype(k.dtype)
+    nan_v = jnp.where(dead[:, None, None], jnp.nan, v).astype(v.dtype)
+    clean = _gate(q, k, v, tables, lens)
+    out = paged_decode_attention_pallas(q, nan_k, nan_v, tables, lens,
+                                        interpret=pltpu.InterpretParams())
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    assert np.array_equal(np.asarray(out, np.float32),
+                          np.asarray(clean, np.float32))
+    mirror = paged_decode_attention_ref(q, nan_k, nan_v, tables, lens)
+    assert np.array_equal(np.asarray(out, np.float32),
+                          np.asarray(mirror, np.float32))
 
 
 def test_single_buffer_rejected():

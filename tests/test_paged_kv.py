@@ -254,6 +254,21 @@ def test_pool_capacity_invariants():
         dense.advance([d])
 
 
+@pytest.mark.parametrize("kind", ["paged", "dense"])
+def test_positions_are_a_snapshot(kind):
+    """``positions()`` feeds a step that may still be running when the host
+    calls ``advance``; the array it returned must keep its values."""
+    cfg = _tiny_cfg()
+    pool = (PagedKVCachePool(cfg, n_slots=2, s_max=16, page_size=8)
+            if kind == "paged" else KVCachePool(cfg, n_slots=2, s_max=16))
+    s = pool.alloc(0)
+    pool.write_prefix(s, _rand_cache(cfg, 5, seed=3), 5)
+    pos = pool.positions()
+    pool.advance([s])
+    assert np.asarray(pos)[s] == 5
+    assert np.asarray(pool.positions())[s] == 6
+
+
 # ---------------------------------------------------------------------------
 # The decode step's in-place layer write (fast)
 # ---------------------------------------------------------------------------

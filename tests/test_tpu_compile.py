@@ -5,8 +5,9 @@ for a described ``v5e:2x2`` topology and refuses what the chip would
 refuse (tile-misaligned kernel slices, VMEM overruns, programs that do not
 fit the device).  Interpret mode catches none of that.  These tests compile
 the serving main path at granite-3-2b's full widths: the paged-decode
-kernel, the IVF-PQ scan at the smoke run's shape, and the bucketed prefill,
-paged fused decode step and chunk-extend programs with bf16 parameters;
+kernel (also at chatglm3-6b's head geometry), the IVF-PQ scan at the smoke
+run's shape, and the bucketed prefill, paged fused decode step and
+chunk-extend programs with bf16 parameters;
 and the decode step at the benchmark cells' shapes (granite-3-2b and
 chatglm3-6b), which must update the donated page pool in place.
 
@@ -91,21 +92,26 @@ def _pallas_attn(q, kp, vp, tables, cache_len):
                                   interpret=False)
 
 
-@pytest.mark.parametrize("d", [GRANITE.d_head, 128], ids=["granite", "d128"])
-def test_paged_decode_kernel_compiles(one_chip, d):
-    h_kv, q_per_kv = GRANITE.n_kv_heads, GRANITE.q_per_kv
-    m = S_MAX // PAGE
-    n_pages = SLOTS * m + SPARE
+@pytest.mark.parametrize("h_kv,q_per_kv,d,slots,m", [
+    (GRANITE.n_kv_heads, GRANITE.q_per_kv, GRANITE.d_head, SLOTS,
+     S_MAX // PAGE),
+    (GRANITE.n_kv_heads, GRANITE.q_per_kv, 128, SLOTS, S_MAX // PAGE),
+    # chatglm3-6b's heads (256-wide page rows) at its benchmark cell's 32
+    # slots and 48-page tables
+    (CHATGLM.n_kv_heads, CHATGLM.q_per_kv, CHATGLM.d_head, 32, 48),
+], ids=["granite", "d128", "chatglm3"])
+def test_paged_decode_kernel_compiles(one_chip, h_kv, q_per_kv, d, slots, m):
+    n_pages = slots * m + SPARE
     f = jax.jit(partial(paged_decode_attention, interpret=False))
     compiled = f.lower(
-        *_spec((jax.ShapeDtypeStruct((SLOTS, 1, h_kv * q_per_kv, d),
+        *_spec((jax.ShapeDtypeStruct((slots, 1, h_kv * q_per_kv, d),
                                      jnp.bfloat16),
                 jax.ShapeDtypeStruct((n_pages, PAGE, h_kv * d),
                                      jnp.bfloat16),
                 jax.ShapeDtypeStruct((n_pages, PAGE, h_kv * d),
                                      jnp.bfloat16),
-                jax.ShapeDtypeStruct((SLOTS, m), jnp.int32),
-                jax.ShapeDtypeStruct((SLOTS,), jnp.int32)), one_chip)
+                jax.ShapeDtypeStruct((slots, m), jnp.int32),
+                jax.ShapeDtypeStruct((slots,), jnp.int32)), one_chip)
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
     _fits(compiled)
