@@ -864,7 +864,7 @@ def main(argv=None) -> dict:
                    help="comma-separated preset names (default: all)")
     p.add_argument("--backends", default="exact,ivfpq")
     p.add_argument("--attn-impl", default="auto",
-                   choices=["auto", "ref", "pallas", "splitk"],
+                   choices=["auto", "ref", "pallas"],
                    help="decode-attention implementation for the preset "
                         "engines (auto: pallas on TPU, ref elsewhere); "
                         "the resolved impl is recorded per row")

@@ -1,12 +1,15 @@
 """Decoder-only transformer (dense + MoE) with GQA, RoPE and SwiGLU.
 
 Layers are stacked along a leading L axis and executed with ``jax.lax.scan``
-so 28-48-layer models compile quickly and produce compact HLO.  Three entry
-points per config:
+so 28-48-layer models compile quickly and produce compact HLO.  Every entry
+point runs the same layer body (``_layer``) and differs only in its scan
+wrapper and its ``attend`` strategy, the cache write and attention:
 
-  * ``forward``        -- full-sequence logits (training / encoder use)
-  * ``prefill``        -- logits + populated KV cache (serving prefix stage)
-  * ``decode_step``    -- one-token autoregressive step against a KV cache
+  * ``forward``            -- full-sequence logits, optionally the K/V
+                              (prefill, training, encoder use)
+  * ``paged_decode_step``  -- one token per sequence against the paged pool
+  * ``paged_chunk_extend`` -- a chunk of one sequence's tokens into the pool
+  * ``decode_step``        -- one token against a dense cache (scaffolding)
 
 MoE uses sort-free capacity dispatch (scatter into an (E, C) buffer per batch
 row) so dispatch memory is O(tokens * top_k * capacity_factor * d_model), not
@@ -235,7 +238,10 @@ def dense_ffn(x: jax.Array, lp: dict, compute_dtype=jnp.bfloat16,
 # ---------------------------------------------------------------------------
 
 def _qkv(x, lp, cfg, positions, compute_dtype):
+    """positions: (B, S), or (B,) for one token per sequence."""
     B, S, _ = x.shape
+    if positions.ndim == 1:
+        positions = positions[:, None]
     wq = cm.maybe_dequant(lp["wq"], compute_dtype)
     wk = cm.maybe_dequant(lp["wk"], compute_dtype)
     wv = cm.maybe_dequant(lp["wv"], compute_dtype)
@@ -248,10 +254,9 @@ def _qkv(x, lp, cfg, positions, compute_dtype):
     return q, k, v
 
 
-def _attn_full_seq(x, lp, cfg, positions, compute_dtype):
-    """Self-attention over a full sequence. Returns (out, k, v)."""
-    B, S, _ = x.shape
-    q, k, v = _qkv(x, lp, cfg, positions, compute_dtype)
+def _attn_full_seq(q, k, v, cfg):
+    """Self-attention of a full sequence over itself: (B, S, H, D)."""
+    S = q.shape[1]
     kr = cm.repeat_kv(k, cfg.q_per_kv)
     vr = cm.repeat_kv(v, cfg.q_per_kv)
     window = cfg.window if cfg.attention == "sliding_window" else None
@@ -259,14 +264,43 @@ def _attn_full_seq(x, lp, cfg, positions, compute_dtype):
         scale = 1.0 / math.sqrt(cfg.d_head)
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, kr).astype(jnp.float32) * scale
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        out = jnp.einsum("bhqk,bkhd->bqhd", probs, vr)
-    elif S > cfg.chunked_attn_threshold:
-        out = cm.chunked_causal_attention(q, kr, vr, cfg.attn_block_kv, window)
-    else:
-        out = cm.naive_causal_attention(q, kr, vr, window)
-    wo = cm.maybe_dequant(lp["wo"], compute_dtype)
-    out = out.reshape(B, S, cfg.n_heads * cfg.d_head) @ wo
-    return out.astype(x.dtype), k, v
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, vr)
+    if S > cfg.chunked_attn_threshold:
+        return cm.chunked_causal_attention(q, kr, vr, cfg.attn_block_kv,
+                                           window)
+    return cm.naive_causal_attention(q, kr, vr, window)
+
+
+def _layer(x, lp, cfg, positions, attend, compute_dtype):
+    """The one decoder layer every entry point runs: rms_norm -> QKV ->
+    ``attend`` -> output projection and residual -> rms_norm -> dense or
+    MoE FFN and residual.
+
+    ``attend(q, k, v) -> (out, attend_out)`` is the entry point's own cache
+    write and attention; ``out`` is (B, S, H, D) and ``attend_out`` is
+    whatever its scan needs back (the layer's K/V, or the updated pool).
+    Returns ``(x, aux, attend_out)``, with ``aux`` the MoE load-balancing
+    loss (None for a dense FFN).  The named scopes are the ones the device
+    trace's readers key on: ``attention``, ``ffn``, and ``kv_write`` inside
+    the paged ``attend``s."""
+    B, S, _ = x.shape
+    with jax.named_scope("attention"):
+        xn = cm.rms_norm(x, lp["ln1"], cfg.norm_eps)
+        q, k, v = _qkv(xn, lp, cfg, positions, compute_dtype)
+    out, attend_out = attend(q, k, v)
+    with jax.named_scope("attention"):
+        wo = cm.maybe_dequant(lp["wo"], compute_dtype)
+        x = x + (out.reshape(B, S, cfg.n_heads * cfg.d_head)
+                 @ wo).astype(x.dtype)
+    aux = None
+    with jax.named_scope("ffn"):
+        xn = cm.rms_norm(x, lp["ln2"], cfg.norm_eps)
+        if cfg.moe is not None:
+            h, aux = moe_ffn(xn, lp, cfg, compute_dtype)
+        else:
+            h = dense_ffn(xn, lp, compute_dtype, cfg.ffn_type)
+        x = x + h
+    return x, aux, attend_out
 
 
 # ---------------------------------------------------------------------------
@@ -289,25 +323,19 @@ def forward(params: dict, tokens: jax.Array, cfg: TransformerConfig,
         x = jnp.take(embed, tokens, axis=0)
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
 
+    def attend(q, k, v):
+        with jax.named_scope("attention"):
+            return _attn_full_seq(q, k, v, cfg), (k, v)
+
     def layer_fn(carry, lp):
         x, aux = carry
         if sp_spec is not None:
             x = jax.lax.with_sharding_constraint(x, sp_spec)
-        with jax.named_scope("attention"):
-            h, k, v = _attn_full_seq(
-                cm.rms_norm(x, lp["ln1"], cfg.norm_eps), lp, cfg, positions,
-                compute_dtype)
-            x = x + h
-        with jax.named_scope("ffn"):
-            xn = cm.rms_norm(x, lp["ln2"], cfg.norm_eps)
-            if cfg.moe is not None:
-                h, a = moe_ffn(xn, lp, cfg, compute_dtype)
+        x, a, kv = _layer(x, lp, cfg, positions, attend, compute_dtype)
+        if a is not None:
+            with jax.named_scope("ffn"):
                 aux = aux + a
-            else:
-                h = dense_ffn(xn, lp, compute_dtype, cfg.ffn_type)
-            x = x + h
-        ys = (k, v) if collect_cache else None
-        return (x, aux), ys
+        return (x, aux), (kv if collect_cache else None)
 
     if remat:
         layer_fn = jax.checkpoint(layer_fn)
@@ -342,12 +370,15 @@ def prefill(params: dict, tokens: jax.Array, cfg: TransformerConfig,
 def decode_step(params: dict, cache: dict, token: jax.Array,
                 pos: jax.Array, cfg: TransformerConfig,
                 compute_dtype=jnp.bfloat16, attn_impl=None):
-    """One autoregressive step.
+    """One autoregressive step against a dense cache.
 
     cache: {"k","v"}: (L, B, S_max, H_kv, D).  token: (B,) int32.
     pos: (B,) int32 -- next position per sequence (== current cache length).
-    ``attn_impl(q, k_cache, v_cache, cache_len) -> (B,1,H,D)`` lets the
-    launcher swap in the distributed split-K attention.
+    ``attn_impl(q, k_cache, v_cache, cache_len) -> (B,1,H,D)`` swaps the
+    attention.  Serving decodes through :func:`paged_decode_step`; this
+    dense step and :func:`abstract_cache` remain only for the seed
+    scaffolding (``launch/steps.py``, ``perf/variants.py``) and go with it
+    (ROADMAP Queue 3, item 1).
     """
     B = token.shape[0]
     embed = cm.maybe_dequant(params["embed"], compute_dtype)
@@ -358,26 +389,21 @@ def decode_step(params: dict, cache: dict, token: jax.Array,
             kr = cm.repeat_kv(kc, cfg.q_per_kv)
             vr = cm.repeat_kv(vc, cfg.q_per_kv)
             return cm.decode_attention_ref(q, kr, vr, cache_len)
+    b_idx = jnp.arange(B)
 
     def layer_fn(x, scanned):
         lp, kc, vc = scanned
-        xn = cm.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q, k_new, v_new = _qkv(xn, lp, cfg, pos[:, None], compute_dtype)
-        # write new token into cache at pos (per-batch-row index)
-        b_idx = jnp.arange(B)
-        kc = kc.astype(compute_dtype).at[b_idx, pos].set(k_new[:, 0])
-        vc = vc.astype(compute_dtype).at[b_idx, pos].set(v_new[:, 0])
-        out = attn(q, kc, vc, pos + 1)
-        wo = cm.maybe_dequant(lp["wo"], compute_dtype)
-        x = x + (out.reshape(B, 1, cfg.n_heads * cfg.d_head) @ wo).astype(x.dtype)
-        xn = cm.rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.moe is not None:
-            h, _ = moe_ffn(xn, lp, cfg, compute_dtype)
-        else:
-            h = dense_ffn(xn, lp, compute_dtype, cfg.ffn_type)
-        return x + h, (kc, vc)
 
-    (x), caches = jax.lax.scan(
+        def attend(q, k, v):
+            # write the new token at pos (per-batch-row index), then attend
+            kc_ = kc.astype(compute_dtype).at[b_idx, pos].set(k[:, 0])
+            vc_ = vc.astype(compute_dtype).at[b_idx, pos].set(v[:, 0])
+            return attn(q, kc_, vc_, pos + 1), (kc_, vc_)
+
+        x, _, kv = _layer(x, lp, cfg, pos, attend, compute_dtype)
+        return x, kv
+
+    x, caches = jax.lax.scan(
         layer_fn, x, (params["layers"], cache["k"], cache["v"]))
     x = cm.rms_norm(x, params["ln_f"], cfg.norm_eps)
     head = cm.maybe_dequant(params["head"], compute_dtype)
@@ -393,22 +419,29 @@ def greedy_generate(params: dict, tokens: jax.Array, lengths: jax.Array,
     tokens: (B, T) int32 prompts, right-padded; lengths: (B,) valid prompt
     lengths.  Returns (B, n_new) int32 generated tokens.  The whole
     generation -- full prefill forward, per-row first-token argmax, and a
-    ``lax.scan`` over decode steps -- runs inside a single XLA program, so
-    jitting this (one compile per prompt bucket x n_new) replaces the
-    eager one-decode-dispatch-per-token loops the serving executors used
-    for query rewriting and multi-query fan-out.
+    ``lax.scan`` of :func:`paged_decode_step` calls -- runs inside a single
+    XLA program, so jitting this (one compile per prompt bucket x n_new)
+    replaces one decode dispatch per token in the serving executors'
+    query rewriting and multi-query fan-out.
 
-    Padding is inert: row b's pad positions >= lengths[b] get garbage K/V
-    from the prefill, but decode step i writes position lengths[b]+i before
-    attending up to it, so every attended slot holds either real prompt
-    K/V or a previously generated token's K/V.
+    The decode steps run on a private page pool: row b owns pages
+    [b*M, (b+1)*M) (the identity block table), with M pages enough for
+    T + n_new positions, and the prefill's K/V is laid into them in the
+    pool's row layout.  Padding is inert: row b's pad positions >=
+    lengths[b] hold garbage K/V from the prefill, but decode step i writes
+    position lengths[b]+i before attending up to it, so every attended
+    row holds either real prompt K/V or a generated token's K/V.
     """
     B, T = tokens.shape
-    logits, _aux, cache = forward(params, tokens, cfg, compute_dtype,
-                                  collect_cache=True)
-    # room for the generated tokens after the longest prompt
-    pad = ((0, 0), (0, 0), (0, n_new), (0, 0), (0, 0))
-    cache = {k: jnp.pad(v, pad) for k, v in cache.items()}
+    page = 16                       # the engine's page size; any size works
+    M = -(-(T + n_new) // page)
+    logits, _aux, kv = forward(params, tokens, cfg, compute_dtype,
+                               collect_cache=True)
+    pad = ((0, 0), (0, 0), (0, M * page - T), (0, 0), (0, 0))
+    row = cfg.n_kv_heads * cfg.d_head
+    cache = {k: jnp.pad(v, pad).reshape(cfg.n_layers, B * M, page, row)
+             for k, v in kv.items()}
+    tables = jnp.arange(B * M, dtype=jnp.int32).reshape(B, M)
     lengths = lengths.astype(jnp.int32)
     first = jnp.argmax(
         logits[jnp.arange(B), lengths - 1, :cfg.vocab_size],
@@ -416,79 +449,13 @@ def greedy_generate(params: dict, tokens: jax.Array, lengths: jax.Array,
 
     def body(carry, _):
         tok, pos, cache = carry
-        lg, cache = decode_step(params, cache, tok, pos, cfg, compute_dtype)
+        lg, cache = paged_decode_step(params, cache, tok, pos, tables, cfg,
+                                      compute_dtype)
         nxt = jnp.argmax(lg[:, :cfg.vocab_size], axis=-1).astype(jnp.int32)
         return (nxt, pos + 1, cache), tok
 
     _, toks = jax.lax.scan(body, (first, lengths, cache), None, length=n_new)
     return toks.T                                     # (B, n_new)
-
-
-def chunk_extend(params: dict, cache: dict, slot: jax.Array,
-                 tokens: jax.Array, start_pos: jax.Array,
-                 n_valid: jax.Array, cfg: TransformerConfig,
-                 compute_dtype=jnp.bfloat16) -> dict:
-    """Extend ONE pool slot's cache with a chunk of tokens in a single
-    forward (iteration prefill for iterative retrieval, §5.3).
-
-    cache: {"k","v"}: (L, B, S_max, H_kv, D) -- the full slot pool.
-    tokens: (T,) int32, padded to T; only the first ``n_valid`` are real.
-    start_pos: scalar int32 -- the slot's current cache length.
-
-    Chunk token i attends to cache positions <= start_pos + i (the slot's
-    existing prefix plus earlier chunk tokens, whose K/V are written first),
-    so the result matches feeding the tokens one decode step at a time.
-    Padding rows write out of bounds (dropped) and their activations are
-    never read, so one compile per power-of-two bucket serves any chunk
-    length.  Logits are not computed -- appended context is prompt, not
-    generation.
-    """
-    s_max = cache["k"].shape[2]
-    T = tokens.shape[0]
-    embed = cm.maybe_dequant(params["embed"], compute_dtype)
-    x = jnp.take(embed, tokens, axis=0)[None]                 # (1, T, d)
-    offs = jnp.arange(T, dtype=jnp.int32)
-    positions = (start_pos + offs)[None]                      # (1, T)
-    # invalid rows target index s_max -> scatter mode="drop" discards them
-    write_pos = jnp.where(offs < n_valid, start_pos + offs, s_max)
-    scale = 1.0 / math.sqrt(cfg.d_head)
-
-    def layer_fn(x, scanned):
-        lp, kc, vc = scanned                    # kc: (B, S_max, H_kv, D)
-        xn = cm.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q, k_new, v_new = _qkv(xn, lp, cfg, positions, compute_dtype)
-        kc = kc.astype(compute_dtype).at[slot, write_pos].set(
-            k_new[0], mode="drop")
-        vc = vc.astype(compute_dtype).at[slot, write_pos].set(
-            v_new[0], mode="drop")
-        kr = cm.repeat_kv(kc[slot][None], cfg.q_per_kv)       # (1, S, H, D)
-        vr = cm.repeat_kv(vc[slot][None], cfg.q_per_kv)
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, kr).astype(
-            jnp.float32) * scale
-        mask = jnp.arange(s_max)[None, None, None, :] <= \
-            positions[0][None, None, :, None]
-        scores = jnp.where(mask, scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        out = jnp.einsum("bhqk,bkhd->bqhd", probs, vr)
-        wo = cm.maybe_dequant(lp["wo"], compute_dtype)
-        x = x + (out.reshape(1, T, cfg.n_heads * cfg.d_head)
-                 @ wo).astype(x.dtype)
-        xn = cm.rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.moe is not None:
-            h, _ = moe_ffn(xn, lp, cfg, compute_dtype)
-        else:
-            h = dense_ffn(xn, lp, compute_dtype, cfg.ffn_type)
-        return x + h, (kc, vc)
-
-    _, caches = jax.lax.scan(
-        layer_fn, x, (params["layers"], cache["k"], cache["v"]))
-    return {"k": caches[0], "v": caches[1]}
-
-
-def make_cache(cfg: TransformerConfig, batch: int, s_max: int,
-               dtype=jnp.bfloat16) -> dict:
-    shape = (cfg.n_layers, batch, s_max, cfg.n_kv_heads, cfg.d_head)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 # ---------------------------------------------------------------------------
@@ -507,9 +474,7 @@ def make_cache(cfg: TransformerConfig, batch: int, s_max: int,
 # block tables to a block-table-native attention impl
 # (``attn(q, k_pages, v_pages, block_tables, cache_len)``).  The default
 # impl gathers the logical view (B, M*page, H, D) and runs reference masked
-# softmax -- when M*page equals the dense s_max that view has the same shape
-# as a dense cache slice and masked softmax zeroes every stale physical row
-# exactly, so paged and dense decode agree token for token.  The Pallas
+# softmax, which zeroes every stale physical row exactly.  The Pallas
 # kernel (``repro.kernels.paged_attention``) honors the same contract
 # without ever materializing the gather.
 
@@ -526,13 +491,14 @@ def paged_decode_step(params: dict, cache: dict, token: jax.Array,
                       attn_impl=None, write_mask: jax.Array | None = None):
     """One autoregressive step against a PAGED KV cache.
 
-    cache: {"k","v"}: (L, P, page, H_kv*D).  token/pos: (B,) int32 as in
-    :func:`decode_step`.  block_tables: (B, M) int32 physical page ids.
+    cache: {"k","v"}: (L, P, page, H_kv*D).  token: (B,) int32.  pos: (B,)
+    int32, each sequence's next position (== its current length).
+    block_tables: (B, M) int32 physical page ids.
     The new token's K/V scatters into physical position
     ``block_tables[b, pos//page]*page + pos%page``; rows with
     ``write_mask`` False (slots not stepping this tick) target the
-    out-of-bounds row ``P*page`` and are dropped, which replaces the
-    dense fused path's whole-cache step-mask merge.
+    out-of-bounds row ``P*page`` and are dropped, so the pool is never
+    touched for them.
 
     ``attn_impl(q, k_pages, v_pages, block_tables, cache_len)`` is
     BLOCK-TABLE-NATIVE: it receives the post-scatter page pool
@@ -576,30 +542,23 @@ def paged_decode_step(params: dict, cache: dict, token: jax.Array,
     def layer_fn(carry, scanned):
         x, k_pool, v_pool = carry                  # pools: (L, P, page, row)
         lp, l = scanned
-        with jax.named_scope("attention"):
-            xn = cm.rms_norm(x, lp["ln1"], cfg.norm_eps)
-            q, k_new, v_new = _qkv(xn, lp, cfg, pos[:, None], compute_dtype)
-        with jax.named_scope("kv_write"):
-            k_pool = k_pool.at[l, page_idx, page_row].set(
-                k_new.reshape(B, row).astype(k_pool.dtype), mode="drop")
-            v_pool = v_pool.at[l, page_idx, page_row].set(
-                v_new.reshape(B, row).astype(v_pool.dtype), mode="drop")
-            kp = jax.lax.dynamic_index_in_dim(
-                k_pool, l, 0, keepdims=False).astype(compute_dtype)
-            vp = jax.lax.dynamic_index_in_dim(
-                v_pool, l, 0, keepdims=False).astype(compute_dtype)
-        with jax.named_scope("attention"):
-            out = attn(q, kp, vp, block_tables, pos + 1)
-            wo = cm.maybe_dequant(lp["wo"], compute_dtype)
-            x = x + (out.reshape(B, 1, cfg.n_heads * cfg.d_head)
-                     @ wo).astype(x.dtype)
-        with jax.named_scope("ffn"):
-            xn = cm.rms_norm(x, lp["ln2"], cfg.norm_eps)
-            if cfg.moe is not None:
-                h, _ = moe_ffn(xn, lp, cfg, compute_dtype)
-            else:
-                h = dense_ffn(xn, lp, compute_dtype, cfg.ffn_type)
-            x = x + h
+
+        def attend(q, k, v):
+            with jax.named_scope("kv_write"):
+                kpool = k_pool.at[l, page_idx, page_row].set(
+                    k.reshape(B, row).astype(k_pool.dtype), mode="drop")
+                vpool = v_pool.at[l, page_idx, page_row].set(
+                    v.reshape(B, row).astype(v_pool.dtype), mode="drop")
+                kp = jax.lax.dynamic_index_in_dim(
+                    kpool, l, 0, keepdims=False).astype(compute_dtype)
+                vp = jax.lax.dynamic_index_in_dim(
+                    vpool, l, 0, keepdims=False).astype(compute_dtype)
+            with jax.named_scope("attention"):
+                out = attn(q, kp, vp, block_tables, pos + 1)
+            return out, (kpool, vpool)
+
+        x, _, (k_pool, v_pool) = _layer(x, lp, cfg, pos, attend,
+                                        compute_dtype)
         return (x, k_pool, v_pool), None
 
     # the pools ride in the carry, not in xs/ys: stacked ys make XLA build
@@ -622,14 +581,16 @@ def paged_chunk_extend(params: dict, cache: dict, block_row: jax.Array,
                        compute_dtype=jnp.bfloat16):
     """Extend ONE sequence's paged cache with a chunk of tokens.
 
-    The paged counterpart of :func:`chunk_extend` -- block_row: (M,) int32,
-    the sequence's page table row.  Chunk token i scatters into the
-    physical row of position ``start_pos + i`` (pad rows and positions
-    past the table drop out of bounds) and attends over the gathered
-    logical view, so the result matches feeding the tokens one decode
-    step at a time.
+    block_row: (M,) int32, the sequence's page table row.  tokens: (T,)
+    int32, padded to T; only the first ``n_valid`` are real.  start_pos:
+    scalar int32, the sequence's current length.  Chunk token i scatters
+    into the physical row of position ``start_pos + i`` (pad rows and
+    positions past the table drop out of bounds) and attends over the
+    gathered logical view, so the result matches feeding the tokens one
+    decode step at a time, and one compile per power-of-two bucket serves
+    any chunk length.
 
-    Unlike the dense version it also returns the last valid row's
+    Returns ``(cache, logits)``, the latter the last valid row's
     next-token logits: chunked prefill consumes a prompt piece by piece
     across decode ticks and reads the request's first token from the
     final chunk, so appended retrieval context and chunked prompt prefill
@@ -653,39 +614,31 @@ def paged_chunk_extend(params: dict, cache: dict, block_row: jax.Array,
 
     def layer_fn(x, scanned):
         lp, kc, vc = scanned                           # (P, page, H_kv*D)
-        with jax.named_scope("attention"):
-            xn = cm.rms_norm(x, lp["ln1"], cfg.norm_eps)
-            q, k_new, v_new = _qkv(xn, lp, cfg, positions, compute_dtype)
-        with jax.named_scope("kv_write"):
-            kf = kc.astype(compute_dtype).reshape(P * page, row)
-            vf = vc.astype(compute_dtype).reshape(P * page, row)
-            kf = kf.at[flat].set(k_new[0].reshape(T, row), mode="drop")
-            vf = vf.at[flat].set(v_new[0].reshape(T, row), mode="drop")
-        with jax.named_scope("attention"):
-            kg = kf.reshape(P, page, row)[block_row]
-            vg = vf.reshape(P, page, row)[block_row]
-            kr = cm.repeat_kv(kg.reshape(1, S, cfg.n_kv_heads, cfg.d_head),
-                              cfg.q_per_kv)
-            vr = cm.repeat_kv(vg.reshape(1, S, cfg.n_kv_heads, cfg.d_head),
-                              cfg.q_per_kv)
-            scores = jnp.einsum("bqhd,bkhd->bhqk", q, kr).astype(
-                jnp.float32) * scale
-            mask = jnp.arange(S)[None, None, None, :] <= \
-                positions[0][None, None, :, None]
-            scores = jnp.where(mask, scores, -jnp.inf)
-            probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-            out = jnp.einsum("bhqk,bkhd->bqhd", probs, vr)
-            wo = cm.maybe_dequant(lp["wo"], compute_dtype)
-            x = x + (out.reshape(1, T, cfg.n_heads * cfg.d_head)
-                     @ wo).astype(x.dtype)
-        with jax.named_scope("ffn"):
-            xn = cm.rms_norm(x, lp["ln2"], cfg.norm_eps)
-            if cfg.moe is not None:
-                h, _ = moe_ffn(xn, lp, cfg, compute_dtype)
-            else:
-                h = dense_ffn(xn, lp, compute_dtype, cfg.ffn_type)
-            x = x + h
-        return x, (kf.reshape(P, page, row), vf.reshape(P, page, row))
+
+        def attend(q, k, v):
+            with jax.named_scope("kv_write"):
+                kf = kc.astype(compute_dtype).reshape(P * page, row)
+                vf = vc.astype(compute_dtype).reshape(P * page, row)
+                kf = kf.at[flat].set(k[0].reshape(T, row), mode="drop")
+                vf = vf.at[flat].set(v[0].reshape(T, row), mode="drop")
+            with jax.named_scope("attention"):
+                kg = kf.reshape(P, page, row)[block_row]
+                vg = vf.reshape(P, page, row)[block_row]
+                kr = cm.repeat_kv(
+                    kg.reshape(1, S, cfg.n_kv_heads, cfg.d_head), cfg.q_per_kv)
+                vr = cm.repeat_kv(
+                    vg.reshape(1, S, cfg.n_kv_heads, cfg.d_head), cfg.q_per_kv)
+                scores = jnp.einsum("bqhd,bkhd->bhqk", q, kr).astype(
+                    jnp.float32) * scale
+                mask = jnp.arange(S)[None, None, None, :] <= \
+                    positions[0][None, None, :, None]
+                scores = jnp.where(mask, scores, -jnp.inf)
+                probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+                out = jnp.einsum("bhqk,bkhd->bqhd", probs, vr)
+            return out, (kf.reshape(P, page, row), vf.reshape(P, page, row))
+
+        x, _, kv = _layer(x, lp, cfg, positions, attend, compute_dtype)
+        return x, kv
 
     with jax.named_scope("kv_write"):
         (x), caches = jax.lax.scan(

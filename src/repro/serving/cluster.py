@@ -12,12 +12,12 @@ the mid-generation work (iterative retrieval dispatch + safety screening of
 iteratively retrieved content), and a finished prefill travels to a decode
 slot as an exported KV-cache prefix (``export_slot`` / ``import_slot`` --
 bit-exact, so a 1+1 cluster is token-for-token identical to the collocated
-single-engine ``RAGServer``).  With the default paged pools the handoff is
-page-granular: the payload carries per-page chain keys, the importing pool
-references pages its prefix cache already holds instead of writing them,
-and only the rest counts as shipped -- ``handoff_bytes`` (shipped, counted
-only after a confirmed import) vs ``handoff_bytes_full`` (what a dense
-whole-prefix export would move), plus ``handoff_pages`` /
+single-engine ``RAGServer``).  The handoff is page-granular: the payload
+carries per-page chain keys, the importing pool references pages its
+prefix cache already holds instead of writing them, and only the rest
+counts as shipped -- ``handoff_bytes`` (shipped, counted only after a
+confirmed import) vs ``handoff_bytes_full`` (the whole prefix's
+payload), plus ``handoff_pages`` /
 ``handoff_pages_shared`` page counts.
 
 Scheduling, per :meth:`RAGCluster.step`:
@@ -163,7 +163,7 @@ class RAGCluster:
              # not transferred
              "handoff_bytes": 0, "handoff_pages": 0,
              "handoff_pages_shared": 0,
-             # what a dense whole-prefix export would have moved
+             # the whole prefix's payload, before dedup
              "handoff_bytes_full": 0,
              # fault layer
              "engine_failures": 0, "requests_retried": 0,

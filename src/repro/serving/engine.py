@@ -21,17 +21,16 @@ Hot-path design:
 * Retrieval goes through a pluggable backend
   (``repro.retrieval.backend``): exact kNN or an IVF-PQ index built at
   construction, selected purely by ``EngineConfig.retrieval_backend``.
-* KV state lives in a PAGED pool by default
+* KV state lives in a paged pool
   (``repro.serving.kv_cache.PagedKVCachePool``): fixed-size pages with a
   per-slot page table, content-addressed full pages shared across
   requests that retrieved the same documents, and page-granular export /
-  import for disaggregated handoff.  ``paged=False`` (implied by
-  ``fused_decode=False``) keeps the dense slot pool for parity testing.
+  import for disaggregated handoff.
 * The decode step is fused: forward + argmax run inside ONE jitted call
-  with the cache donated to XLA, so each token costs a single dispatch
-  and a single (B,)-token device->host transfer.  On the paged pool,
-  slots that are not stepping scatter their write out of bounds (dropped)
-  instead of paying the dense path's whole-cache step-mask merge.
+  (``tr.paged_decode_step``) with the pool donated to XLA, so each token
+  costs a single dispatch and a single (B,)-token device->host transfer.
+  Slots that are not stepping scatter their write out of bounds
+  (dropped), so the pool is never touched for them.
 * Iteratively retrieved context AND chunked prompt prefill share one
   bucketed chunk-extend program (``tr.paged_chunk_extend``): one jitted
   forward per power-of-two chunk bucket writes the slot's pages directly.
@@ -49,11 +48,9 @@ number of distinct buckets.
 ``metrics`` counts the transfers the hot path pays: ``host_syncs`` (the
 device->host copies made by the engine's own primitives -- one per prefill
 first-token fetch, one per stepping decode step, one per ``retrieve``
-batch; executors' internal transfers are not counted), ``decode_host_syncs``
-(the decode loop's share -- exactly one per stepping decode step when
-fused), and ``cache_copy_bytes`` (bytes of whole-cache device copies spent
-merging decode results -- zero when fused, two full caches per step
-otherwise).
+batch; executors' internal transfers are not counted) and
+``decode_host_syncs`` (the decode loop's share -- exactly one per stepping
+decode step).
 """
 
 from __future__ import annotations
@@ -73,7 +70,7 @@ from repro.models.common import named
 from repro.retrieval.backend import (ExactBackend, FallbackBackend,
                                      make_backend)
 from repro.serving.faults import EngineCrash, EngineHealth
-from repro.serving.kv_cache import KVCachePool, PagedKVCachePool
+from repro.serving.kv_cache import PagedKVCachePool
 from repro.serving.request import Request, State
 from repro.serving.telemetry import (NULL_TRACER, PROFILER_STAGE_NAMES,
                                      MetricsRegistry, stage_kind)
@@ -106,17 +103,13 @@ class EngineConfig:
     # graceful degradation: wrap the backend in a FallbackBackend chain
     # (primary -> exact scan -> no-context); bit-transparent without faults
     retrieval_fallback: bool = True
-    # decode-step fusion (False keeps the pre-fusion path for parity tests)
-    fused_decode: bool = True
     # decode attention implementation.  "auto" resolves at engine
     # construction: the Pallas paged kernel on TPU, the reference
     # gather+softmax path elsewhere.  "pallas" forces the kernel (interpret
-    # mode off-TPU -- CPU CI runs it bit-gated), "splitk" the distributed
-    # flash-decoding attention from repro.distributed.decode_attn.
-    attn_impl: str = "auto"              # "auto" | "ref" | "pallas" | "splitk"
+    # mode off-TPU -- CPU CI runs it bit-gated).
+    attn_impl: str = "auto"              # "auto" | "ref" | "pallas"
     attn_num_buffers: int = 2            # DMA staging buffers (2=double, 4=quad)
     # paged KV cache + continuous batching
-    paged: bool = True                   # page-table pool (False: dense slots)
     page_size: int = 16                  # tokens per KV page
     kv_spare_pages: int | None = None    # extra pages kept as prefix cache
     prefill_chunk: int | None = None     # >0: chunk prefill across ticks
@@ -136,26 +129,17 @@ class EngineConfig:
             raise ValueError(f"page_size={self.page_size} must be positive")
         if self.iter_query_tokens <= 0:
             raise ValueError("iter_query_tokens must be positive")
-        if self.attn_impl not in ("auto", "ref", "pallas", "splitk"):
+        if self.attn_impl not in ("auto", "ref", "pallas"):
             raise ValueError(
                 f"attn_impl={self.attn_impl!r} must be one of "
-                "'auto', 'ref', 'pallas', 'splitk'")
+                "'auto', 'ref', 'pallas'")
         if self.attn_num_buffers < 2:
             raise ValueError(
                 f"attn_num_buffers={self.attn_num_buffers} must be >= 2 "
                 "(one page in flight while computing another)")
-        if not self.fused_decode:
-            # the pre-fusion parity path predates paging; it decodes
-            # against the dense slot pool
-            self.paged = False
-        if self.prefill_chunk is not None:
-            if self.prefill_chunk <= 0:
-                raise ValueError(
-                    f"prefill_chunk={self.prefill_chunk} must be positive")
-            if not self.paged:
-                raise ValueError(
-                    "chunked prefill requires the paged KV pool "
-                    "(paged=True with fused_decode=True)")
+        if self.prefill_chunk is not None and self.prefill_chunk <= 0:
+            raise ValueError(
+                f"prefill_chunk={self.prefill_chunk} must be positive")
 
     @classmethod
     def from_schema(cls, schema, **overrides) -> "EngineConfig":
@@ -203,11 +187,9 @@ class RAGEngine:
         self.safety = safety
         self.cfg = cfg
         self.corpus = np.asarray(corpus_tokens)
-        self.pool = (PagedKVCachePool(generative.cfg, cfg.decode_slots,
-                                      cfg.s_max, page_size=cfg.page_size,
-                                      spare_pages=cfg.kv_spare_pages)
-                     if cfg.paged else
-                     KVCachePool(generative.cfg, cfg.decode_slots, cfg.s_max))
+        self.pool = PagedKVCachePool(generative.cfg, cfg.decode_slots,
+                                     cfg.s_max, page_size=cfg.page_size,
+                                     spare_pages=cfg.kv_spare_pages)
         self.queue: list[Request] = []
         self.active: dict[int, Request] = {}     # slot -> request
         self.prefilling: dict[int, int] = {}     # slot -> prompt cursor
@@ -218,7 +200,7 @@ class RAGEngine:
              "prefills": 0,
              "prefill_compiles": 0, "append_compiles": 0,
              "host_syncs": 0, "decode_host_syncs": 0,
-             "cache_copy_bytes": 0, "capacity_stops": 0,
+             "capacity_stops": 0,
              "degraded_answers": 0, "stage_time_s": {}})
         # telemetry: no-op by default (zero-cost-when-off); a server or
         # cluster swaps in a SpanTracer via set_tracer
@@ -234,17 +216,12 @@ class RAGEngine:
         # resolved decode-attention implementation ("auto" picks by backend)
         self.attn_impl = cfg.attn_impl if cfg.attn_impl != "auto" else (
             "pallas" if jax.default_backend() == "tpu" else "ref")
-        paged_attn, dense_attn = self._make_attn_impls()
-        self._decode_jit = jax.jit(partial(tr.decode_step, cfg=self.gen.cfg,
-                                           attn_impl=dense_attn))
-        self._fused_decode_jit = jax.jit(
-            partial(self._fused_decode, cfg=self.gen.cfg, attn=dense_attn),
-            donate_argnums=(1,))
         # the serving path's programs carry fixed names (jit_rago_*) on
         # the device trace
         self._paged_decode_jit = jax.jit(
             named("rago_decode", partial(self._paged_fused_decode,
-                                         cfg=self.gen.cfg, attn=paged_attn)),
+                                         cfg=self.gen.cfg,
+                                         attn=self._make_attn_impl())),
             donate_argnums=(1,))
         self._encode_jit = jax.jit(
             named("rago_encode", partial(tr.encode, cfg=self.enc.cfg)))
@@ -341,47 +318,23 @@ class RAGEngine:
 
     # ---------------- shared primitives -----------------------------------
 
-    def _make_attn_impls(self):
-        """Build the (paged, dense) decode-attention callables for the
-        resolved ``attn_impl``.
-
-        The callables are closed over by the jitted decode programs via
-        ``functools.partial`` at construction -- jit never sees them as
-        arguments, so swapping implementations costs nothing per step.
-        ``(None, None)`` keeps the transformer entry points' built-in
-        reference paths (gather + masked softmax), which is what every
-        engine computed before this knob existed.
-        """
+    def _make_attn_impl(self):
+        """The paged decode-attention callable for the resolved
+        ``attn_impl``: ``None`` for "ref" (``tr.paged_decode_step``'s
+        built-in gather + masked softmax), the Pallas paged kernel for
+        "pallas".  The decode program closes over it via
+        ``functools.partial`` at construction, so jit never sees it as an
+        argument and the choice costs nothing per step."""
         if self.attn_impl == "ref":
-            return None, None
-        if self.attn_impl == "pallas":
-            from repro.kernels.decode_attention.ops import decode_attention
-            from repro.kernels.paged_attention.ops import (
-                paged_decode_attention)
-            nb = self.cfg.attn_num_buffers
-
-            def paged_attn(q, kp, vp, tables, cache_len):
-                return paged_decode_attention(q, kp, vp, tables, cache_len,
-                                              num_buffers=nb)
-
-            return paged_attn, decode_attention
-        # splitk: flash-decoding sharded over the host mesh's model axis
-        # (trivially 1 shard on a single device; the point is wiring the
-        # distributed path into the engine with engine-identical tokens)
-        from repro.distributed.decode_attn import make_distributed_decode_attn
-        from repro.kernels.paged_attention.ref import paged_gather
-        from repro.launch.mesh import make_host_mesh
-        dense_attn = make_distributed_decode_attn(make_host_mesh(),
-                                                  self.gen.cfg.q_per_kv)
+            return None
+        from repro.kernels.paged_attention.ops import paged_decode_attention
+        nb = self.cfg.attn_num_buffers
 
         def paged_attn(q, kp, vp, tables, cache_len):
-            # split-K shards the sequence axis of a dense view, so this
-            # adapter gathers it; only the "pallas" impl is gather-free
-            d = q.shape[-1]
-            return dense_attn(q, paged_gather(kp, tables, d),
-                              paged_gather(vp, tables, d), cache_len)
+            return paged_decode_attention(q, kp, vp, tables, cache_len,
+                                          num_buffers=nb)
 
-        return paged_attn, dense_attn
+        return paged_attn
 
     def has_executor(self, name: str) -> bool:
         return any(ex.name == name for ex in self.executors)
@@ -614,33 +567,11 @@ class RAGEngine:
     # ---------------- decode loop ------------------------------------------
 
     def _append_tokens(self, slot: int, tokens: np.ndarray) -> None:
-        """Append retrieved content into a slot's cache (iteration prefill).
-
-        Bucketed chunk append: the tokens are padded to the next power-of-
-        two bucket and one jitted ``tr.chunk_extend`` forward writes the
-        slot's cache prefix directly (cache donated, pad rows dropped), so
-        an n-token append costs one dispatch instead of n decode steps."""
-        t = len(tokens)
-        if t == 0:
-            return
-        if isinstance(self.pool, PagedKVCachePool):
+        """Append retrieved content into a slot's cache (iteration prefill)
+        through the bucketed chunk extend, so an n-token append costs one
+        dispatch instead of n decode steps."""
+        if len(tokens):
             self._paged_extend(slot, np.asarray(tokens, np.int32))
-            return
-        bucket = bucket_len(t)
-        fn = self._append_jit.get(bucket)
-        if fn is None:
-            fn = jax.jit(partial(tr.chunk_extend, cfg=self.gen.cfg),
-                         donate_argnums=(1,))
-            self._append_jit[bucket] = fn
-            self.metrics["append_compiles"] += 1
-        padded = np.zeros(bucket, np.int32)
-        padded[:t] = tokens
-        self.pool.cache = fn(
-            self.gen.params, self.pool.cache,
-            jnp.asarray(slot, jnp.int32), jnp.asarray(padded),
-            jnp.asarray(self.pool.lengths[slot], jnp.int32),
-            jnp.asarray(t, jnp.int32))
-        self.pool.lengths[slot] += t
 
     def _paged_extend(self, slot: int, tokens: np.ndarray) -> jnp.ndarray:
         """Bucketed paged chunk extend: allocate/COW the pages the write
@@ -725,31 +656,16 @@ class RAGEngine:
                 req.state = State.DECODE
 
     @staticmethod
-    def _fused_decode(params, cache, token_vec, positions, step_mask, *,
-                      cfg, attn=None):
-        """One fused decode step: forward + argmax + active-slot cache
-        merge in a single XLA program.  ``step_mask`` (B,) bool selects the
-        slots that actually decoded; other slots keep their old cache rows
-        (the step wrote a garbage token at their current position).  The
-        cache argument is donated, so the merge is an in-place update."""
-        logits, new_cache = tr.decode_step(params, cache, token_vec,
-                                           positions, cfg, attn_impl=attn)
-        tokens = jnp.argmax(logits[:, :cfg.vocab_size], axis=-1)
-        mask = step_mask[None, :, None, None, None]     # (L, B, S, H, D)
-        merged = jax.tree_util.tree_map(
-            lambda new, old: jnp.where(mask, new, old), new_cache, cache)
-        return tokens.astype(jnp.int32), merged
-
-    @staticmethod
     def _paged_fused_decode(params, cache, token_vec, positions,
                             block_tables, step_mask, *, cfg, attn=None):
         """Fused decode against the paged pool: forward + argmax in one
-        donated XLA program.  No step-mask cache merge is needed -- slots
-        that are not stepping simply scatter their K/V write out of
-        bounds (dropped), so the page pool is never touched for them;
-        they read the same post-scatter pool bytes whichever ``attn``
-        implementation runs, which is why the attention kernel needs no
-        write-mask handling of its own."""
+        donated XLA program.  Slots that are not stepping scatter their
+        K/V write out of bounds (dropped), so the page pool is never
+        touched for them; they read the same post-scatter pool bytes
+        whichever ``attn`` implementation runs, which is why the attention
+        kernel needs no write-mask handling of its own.  ``tr`` is read at
+        trace time, so a patched ``tr.paged_decode_step`` reaches this
+        program."""
         logits, cache = tr.paged_decode_step(
             params, cache, token_vec, positions, block_tables, cfg,
             attn_impl=attn, write_mask=step_mask)
@@ -788,43 +704,20 @@ class RAGEngine:
     def _decode_active(self, token_vec, stepping) -> None:
         # profiler sub-spans of rago.decode (no-ops with tracing off)
         span, tick = self.tracer.profiler_span, self.tick_no
-        if isinstance(self.pool, PagedKVCachePool):
-            with span("rago.decode.prepare", tick):
-                for slot in stepping:    # allocate/COW each write target
-                    self.pool.prepare_append(slot, 1)
-                step_mask = np.zeros(self.pool.n_slots, bool)
-                step_mask[stepping] = True
-                positions = self.pool.positions()
-                tables = jnp.asarray(self.pool.block_tables())
-            with span("rago.decode.launch", tick):
-                toks, self.pool.cache = self._paged_decode_jit(
-                    self.gen.params, self.pool.cache,
-                    jnp.asarray(token_vec), positions, tables,
-                    jnp.asarray(step_mask))
-            with span("rago.decode.fetch", tick):
-                new_tokens = np.asarray(toks)        # the step's one sync
-        elif self.cfg.fused_decode:
+        with span("rago.decode.prepare", tick):
+            for slot in stepping:        # allocate/COW each write target
+                self.pool.prepare_append(slot, 1)
             step_mask = np.zeros(self.pool.n_slots, bool)
             step_mask[stepping] = True
-            toks, self.pool.cache = self._fused_decode_jit(
-                self.gen.params, self.pool.cache, jnp.asarray(token_vec),
-                self.pool.positions(), jnp.asarray(step_mask))
+            positions = self.pool.positions()
+            tables = jnp.asarray(self.pool.block_tables())
+        with span("rago.decode.launch", tick):
+            toks, self.pool.cache = self._paged_decode_jit(
+                self.gen.params, self.pool.cache,
+                jnp.asarray(token_vec), positions, tables,
+                jnp.asarray(step_mask))
+        with span("rago.decode.fetch", tick):
             new_tokens = np.asarray(toks)            # the step's one sync
-        else:
-            # pre-fusion path (kept for parity tests): host-side argmax
-            # plus a full tree_map cache rebuild per step
-            logits, cache = self._decode_jit(
-                self.gen.params, self.pool.cache, jnp.asarray(token_vec),
-                self.pool.positions())
-            new_tokens = np.asarray(
-                jnp.argmax(logits[:, :self.gen.cfg.vocab_size], axis=-1))
-            # keep cache rows only for slots that actually decoded
-            self.pool.cache = jax.tree_util.tree_map(
-                lambda new, old: old.at[:, np.asarray(stepping)].set(
-                    new[:, np.asarray(stepping)]),
-                cache, self.pool.cache)
-            self.metrics["cache_copy_bytes"] += sum(
-                v.nbytes for v in self.pool.cache.values())
         with span("rago.decode.commit", tick):
             self._commit_tokens(new_tokens, stepping)
 
@@ -873,7 +766,7 @@ class RAGEngine:
 
     def metrics_snapshot(self) -> dict:
         """Engine counters merged with the KV pool's page counters
-        (``pages_allocated``/``pages_shared``/... for the paged pool).
+        (``pages_allocated``/``pages_shared``/...).
 
         The snapshot is fully detached: every nested structure (including
         ``stage_time_s`` and the latency histograms) is a fresh copy, so
@@ -884,7 +777,7 @@ class RAGEngine:
         if isinstance(self.backend, FallbackBackend):
             out["retrieval_fallbacks"] = self.backend.metrics["fallbacks"]
             out["retrieval_no_context"] = self.backend.metrics["no_context"]
-        out.update(dict(getattr(self.pool, "metrics", {})))
+        out.update(self.pool.metrics)
         return out
 
     def abort_request(self, req: Request, reason: str,

@@ -170,13 +170,11 @@ class FaultInjector:
         return hit
 
     def corrupt(self, payload):
-        """Bit-flip one K-page of an exported KV payload in place
+        """Bit-flip the first K-page of an exported
+        :class:`~repro.serving.kv_cache.PagedPrefix` in place
         (deterministically, via the plan-seeded RNG) -- simulates wire
-        corruption.  Works on both handoff payload layouts: the paged
-        :class:`~repro.serving.kv_cache.PagedPrefix` and the dense
-        ``{"k","v"}`` dict."""
-        arrays = (list(payload.pages.values())[0]
-                  if hasattr(payload, "pages") else payload)
+        corruption."""
+        arrays = next(iter(payload.pages.values()))
         buf = np.asarray(arrays["k"]).view(np.uint8).copy()
         pos = int(self.rng.integers(buf.size))
         buf.flat[pos] ^= 0xFF

@@ -1,29 +1,21 @@
-"""KV cache pools for continuous batching: dense slots and paged pages.
+"""The paged KV cache pool for continuous batching.
 
 XLA needs static shapes, so the decode batch is a fixed pool of ``n_slots``
-sequences.  Two storage layouts implement the same pool protocol
-(``alloc``/``release``/``write_prefix``/``export_slot``/``import_slot``/
-``positions``/``advance``):
+sequences.  :class:`PagedKVCachePool` stores fixed-size pages as page rows
+(L, n_pages, page, H_kv*D) with a per-slot page table.  Slots only hold the
+pages their length covers, full pages are content-addressed by a chained
+token hash so requests retrieving the same documents SHARE context pages
+(RAGPulse-style prefix caching, refcounted with copy-on-extend), and a
+handoff ships pages (:class:`PagedPrefix`): the destination pool re-keys
+the payload and pages it already holds are referenced instead of
+transferred (``ImportStats`` reports what actually shipped).
 
-* :class:`KVCachePool` -- the original dense layout, one ``s_max``-wide
-  cache row per slot: (L, n_slots, S_max, H_kv, D).  Every request pays
-  ``s_max`` worth of HBM and a handoff ships the whole prefix.
-* :class:`PagedKVCachePool` -- fixed-size pages stored as page rows
-  (L, n_pages, page, H_kv*D) with a per-slot page table.  Slots only
-  hold the pages their length covers, full pages are content-addressed
-  by a chained token hash so requests retrieving the same documents
-  SHARE context pages (RAGPulse-style prefix caching, refcounted with
-  copy-on-extend), and a
-  handoff ships pages, not a dense prefix: the destination pool re-keys
-  the payload and pages it already holds are referenced instead of
-  transferred (``ImportStats`` reports what actually shipped).
-
-Both layouts keep the handoff bit-exact: the prefix travels as host numpy
-arrays in the pool's own dtype (bf16 via ml_dtypes), and a shared page is
-only ever substituted for a bit-identical one -- page keys are chained
-hashes of the token ids *and* the producing prefill's padded bucket length,
-so two prompts only share a page when the prefill math for those positions
-was the exact same XLA program on the exact same inputs.
+The handoff is bit-exact: the prefix travels as host numpy arrays in the
+pool's own dtype (bf16 via ml_dtypes), and a shared page is only ever
+substituted for a bit-identical one -- page keys are chained hashes of the
+token ids *and* the producing prefill's padded bucket length, so two
+prompts only share a page when the prefill math for those positions was
+the exact same XLA program on the exact same inputs.
 
 Pool invariant (asserted): ``lengths[slot] <= s_max`` at all times -- a KV
 write past ``s_max`` would be silently dropped by the scatter and the
@@ -79,9 +71,10 @@ class PagedPrefix:
     ``keys[j]`` is the chain key of logical page j (None for the partial
     tail page, which is never content-addressed), ``pages[j]`` the page's
     valid K/V rows as host arrays: {"k","v"}: (L, rows<=page, H_kv, D).
-    Only the valid rows of the tail page travel, so ``nbytes`` equals the
-    dense whole-prefix payload; the *shipped* savings come from the
-    importer referencing pages it already caches instead of writing them.
+    Only the valid rows of the tail page travel, so ``nbytes`` is the whole
+    prefix, 2 * L * length * H_kv * D * itemsize; the *shipped* savings
+    come from the importer referencing pages it already caches instead of
+    writing them.
     """
     page_size: int
     length: int
@@ -90,98 +83,9 @@ class PagedPrefix:
 
     @property
     def nbytes(self) -> int:
-        """Total payload size == what a dense whole-prefix export ships."""
+        """Total payload size: every page's valid rows."""
         return int(sum(v.nbytes for p in self.pages.values()
                        for v in p.values()))
-
-
-class KVCachePool:
-    """Dense slot-per-request pool (kept for parity with the paged layout
-    and for the pre-fusion decode path)."""
-
-    def __init__(self, cfg: tr.TransformerConfig, n_slots: int, s_max: int,
-                 dtype=jnp.bfloat16):
-        self.cfg = cfg
-        self.n_slots = n_slots
-        self.s_max = s_max
-        self.cache = tr.make_cache(cfg, n_slots, s_max, dtype)
-        self.lengths = np.zeros(n_slots, np.int32)
-        self.free = list(range(n_slots))
-        self.owner: dict[int, int] = {}       # slot -> request id
-
-    def alloc(self, rid: int) -> int | None:
-        if not self.free:
-            return None
-        slot = self.free.pop()
-        self.owner[slot] = rid
-        self.lengths[slot] = 0
-        return slot
-
-    def release(self, slot: int) -> None:
-        self.owner.pop(slot, None)
-        self.lengths[slot] = 0
-        # zero the slot so stale keys can never leak across requests
-        self.cache = {
-            k: v.at[:, slot].set(0) for k, v in self.cache.items()}
-        self.free.append(slot)
-
-    def write_prefix(self, slot: int, layer_cache: dict, prefix_len: int,
-                     tokens=None, key_salt: bytes = b""):
-        """Install a prefill-produced cache (L, 1, P, H, D) into the slot.
-
-        ``tokens``/``key_salt`` are accepted for protocol compatibility
-        with the paged pool and ignored (dense slots cannot share)."""
-        p = min(prefix_len, self.s_max)
-        self.cache = {
-            k: self.cache[k].at[:, slot, :p].set(v[:, 0, :p])
-            for k, v in layer_cache.items()}
-        self.lengths[slot] = p
-
-    def export_slot(self, slot: int) -> tuple[dict, int]:
-        """Extract the slot's valid cache prefix for a KV handoff.
-
-        Returns ``({"k","v"}: (L, length, H_kv, D) host arrays, length)``
-        in the pool dtype -- no precision is lost in transit, so an
-        ``import_slot`` of the result is bit-exact."""
-        length = int(self.lengths[slot])
-        prefix = {k: np.asarray(v[:, slot, :length])
-                  for k, v in self.cache.items()}
-        return prefix, length
-
-    def import_slot(self, slot: int, prefix: dict, length: int) -> ImportStats:
-        """Install an exported cache prefix into a (freshly alloc'd) slot.
-
-        Raises if the prefix does not fit: truncating it would silently
-        decode from a corrupted context (the request's first token was
-        sampled at a position past the cut), breaking the bit-exact
-        handoff contract -- a cluster must pair pools of equal
-        ``s_max``."""
-        p = int(length)
-        if p > self.s_max:
-            raise ValueError(
-                f"cannot import a {p}-token cache prefix into a pool with "
-                f"s_max={self.s_max}; prefill and decode pools must agree")
-        self.cache = {
-            k: self.cache[k].at[:, slot, :p].set(jnp.asarray(prefix[k][:, :p]))
-            for k in self.cache}
-        self.lengths[slot] = p
-        return ImportStats(self.handoff_bytes(prefix), 0, 0)
-
-    @staticmethod
-    def handoff_bytes(prefix: dict) -> int:
-        """Payload size of one exported prefix (handoff traffic accounting)."""
-        return int(sum(v.nbytes for v in prefix.values()))
-
-    def positions(self) -> jnp.ndarray:
-        # a copy: on the CPU ``jnp.asarray`` shares the host buffer, and a
-        # dispatched step would see ``advance`` bump it under its feet
-        return jnp.array(self.lengths)
-
-    def advance(self, slots: list[int]) -> None:
-        for s in slots:
-            self.lengths[s] += 1
-            assert self.lengths[s] <= self.s_max, \
-                f"slot {s} advanced past s_max={self.s_max}"
 
 
 class PagedKVCachePool:
@@ -409,8 +313,10 @@ class PagedKVCachePool:
         """Install a handed-off prefix, page by page.  Keyed pages already
         present in this pool's prefix cache are referenced (bit-identical
         by key construction) and their payload is NOT counted as shipped;
-        everything else is written and registered.  Bit-exactness of the
-        round trip is the same contract as the dense pool's."""
+        everything else is written and registered; the round trip is
+        bit-exact.  Raises if the prefix does not fit: truncating it would
+        silently decode from a corrupted context, so a cluster must pair
+        pools of equal ``s_max``."""
         if not isinstance(prefix, PagedPrefix):
             raise TypeError("paged pool can only import a PagedPrefix")
         if prefix.page_size != self.page_size:
@@ -450,11 +356,6 @@ class PagedKVCachePool:
         self.lengths[slot] = p
         return ImportStats(shipped_bytes, shipped, shared)
 
-    @staticmethod
-    def handoff_bytes(prefix: PagedPrefix) -> int:
-        """Full payload size (== dense equivalent; see PagedPrefix)."""
-        return prefix.nbytes
-
     # ---------------- decode-loop interface --------------------------------
 
     def block_tables(self) -> np.ndarray:
@@ -482,48 +383,36 @@ class PagedKVCachePool:
                 f"slot {s} advanced past s_max={self.s_max}"
 
 
-def payload_nbytes(prefix) -> int:
-    """Dense-equivalent payload size of any exported prefix."""
-    if isinstance(prefix, PagedPrefix):
-        return prefix.nbytes
-    return KVCachePool.handoff_bytes(prefix)
+def payload_nbytes(prefix: PagedPrefix) -> int:
+    """Whole-prefix payload size of an exported prefix."""
+    return prefix.nbytes
 
 
-def payload_summary(prefix, length: int) -> dict:
-    """Span-attribution view of a handoff payload: token count, dense
-    payload bytes and (paged layout) page count -- the byte/token sizes
-    the telemetry layer attaches to each HANDOFF span.  Tolerates a
-    payload already lost in transit (``None``)."""
+def payload_summary(prefix: PagedPrefix | None, length: int) -> dict:
+    """Span-attribution view of a handoff payload: token count, payload
+    bytes and page count -- the sizes the telemetry layer attaches to each
+    HANDOFF span.  Tolerates a payload already lost in transit
+    (``None``)."""
     if prefix is None:
         return {"tokens": int(length), "bytes_full": 0, "pages": 0}
-    out = {"tokens": int(length), "bytes_full": payload_nbytes(prefix)}
-    if isinstance(prefix, PagedPrefix):
-        out["pages"] = len(prefix.pages)
-    return out
+    return {"tokens": int(length), "bytes_full": prefix.nbytes,
+            "pages": len(prefix.pages)}
 
 
-def payload_checksum(prefix) -> int:
-    """CRC32 over an exported KV payload's bytes (+ its logical layout).
+def payload_checksum(prefix: PagedPrefix) -> int:
+    """CRC32 over an exported KV payload's bytes and logical layout (page
+    order, chain keys and page bytes all feed the sum).
 
     Computed at export and verified before import, so a handoff payload
     corrupted or truncated "on the wire" is REJECTED and the request
     retried instead of decoding from a garbage context -- the fault
-    layer's end of the bit-exact handoff contract.  Covers both layouts:
-    the paged :class:`PagedPrefix` (page order, chain keys and page bytes
-    all feed the sum) and the dense ``{"k","v"}`` dict."""
-    crc = 0
-    if isinstance(prefix, PagedPrefix):
-        crc = zlib.crc32(
-            f"{prefix.page_size}:{prefix.length}".encode(), crc)
-        for j in sorted(prefix.pages):
-            key = prefix.keys[j] if j < len(prefix.keys) else None
-            crc = zlib.crc32(key or b"\0", crc)
-            page = prefix.pages[j]
-            for name in sorted(page):
-                crc = zlib.crc32(np.ascontiguousarray(
-                    np.asarray(page[name])).view(np.uint8), crc)
-        return crc
-    for name in sorted(prefix):
-        crc = zlib.crc32(np.ascontiguousarray(
-            np.asarray(prefix[name])).view(np.uint8), crc)
+    layer's end of the bit-exact handoff contract."""
+    crc = zlib.crc32(f"{prefix.page_size}:{prefix.length}".encode())
+    for j in sorted(prefix.pages):
+        key = prefix.keys[j] if j < len(prefix.keys) else None
+        crc = zlib.crc32(key or b"\0", crc)
+        page = prefix.pages[j]
+        for name in sorted(page):
+            crc = zlib.crc32(np.ascontiguousarray(
+                np.asarray(page[name])).view(np.uint8), crc)
     return crc

@@ -20,7 +20,7 @@ from repro.core.ragschema import case_I
 from repro.core.serving_plan import ServingPlan
 from repro.core.stage_registry import DECODE, REGISTRY
 from repro.models import transformer as tr
-from repro.serving.kv_cache import KVCachePool
+from repro.serving.kv_cache import PagedKVCachePool, payload_nbytes
 from repro.serving.request import (LEGAL_TRANSITIONS, Request, State)
 from repro.serving.trace import (TraceEntry, bursty_trace, load_trace,
                                  save_trace)
@@ -44,8 +44,8 @@ def test_kv_export_import_bit_exact():
     token-for-token equal to collocated decode."""
     import jax.numpy as jnp
     cfg = _tiny_cfg()
-    src = KVCachePool(cfg, n_slots=3, s_max=16)
-    dst = KVCachePool(cfg, n_slots=2, s_max=16)
+    src = PagedKVCachePool(cfg, n_slots=3, s_max=16, page_size=4)
+    dst = PagedKVCachePool(cfg, n_slots=2, s_max=16, page_size=4)
     rng = np.random.default_rng(0)
     prefix_len = 11
     layer_cache = {
@@ -58,28 +58,31 @@ def test_kv_export_import_bit_exact():
 
     kv, length = src.export_slot(slot)
     assert length == prefix_len
-    assert kv["k"].shape == (cfg.n_layers, prefix_len, cfg.n_kv_heads,
-                             cfg.d_head)
-    assert KVCachePool.handoff_bytes(kv) == sum(v.nbytes
-                                                for v in kv.values())
+    got = {k: np.concatenate([kv.pages[j][k] for j in sorted(kv.pages)],
+                             axis=1) for k in ("k", "v")}
+    assert got["k"].shape == (cfg.n_layers, prefix_len, cfg.n_kv_heads,
+                              cfg.d_head)
+    assert payload_nbytes(kv) == sum(v.nbytes for v in got.values())
     dslot = dst.alloc(rid=1)
     dst.import_slot(dslot, kv, length)
     assert int(dst.lengths[dslot]) == prefix_len
     for k in ("k", "v"):
-        a = np.asarray(src.cache[k][:, slot, :prefix_len])
-        b = np.asarray(dst.cache[k][:, dslot, :prefix_len])
-        assert a.dtype == b.dtype            # no precision lost in transit
-        assert np.array_equal(a, b)
-    # the tail beyond the prefix stays zeroed in the destination
-    assert not np.asarray(dst.cache["k"][:, dslot, prefix_len:]).any()
+        want = np.asarray(layer_cache[k][:, 0])
+        assert got[k].dtype == want.dtype    # no precision lost in transit
+        assert np.array_equal(got[k], want)
+        rows = np.asarray(dst.cache[k][:, dst.page_tables[dslot]]).reshape(
+            cfg.n_layers, -1, cfg.n_kv_heads, cfg.d_head)
+        assert np.array_equal(rows[:, :prefix_len], want)
+        # the tail page's rows past the prefix stay zeroed
+        assert not rows[:, prefix_len:].any()
 
 
 def test_kv_import_rejects_oversized_prefix():
     """Truncating a handoff would decode from a corrupted context, so a
     prefix that does not fit the destination pool raises instead."""
     cfg = _tiny_cfg()
-    src = KVCachePool(cfg, n_slots=1, s_max=16)
-    dst = KVCachePool(cfg, n_slots=1, s_max=8)       # smaller pool
+    src = PagedKVCachePool(cfg, n_slots=1, s_max=16, page_size=4)
+    dst = PagedKVCachePool(cfg, n_slots=1, s_max=8, page_size=4)
     import jax.numpy as jnp
     layer_cache = {k: jnp.ones((cfg.n_layers, 1, 12, cfg.n_kv_heads,
                                 cfg.d_head), jnp.bfloat16)
@@ -90,6 +93,7 @@ def test_kv_import_rejects_oversized_prefix():
     d = dst.alloc(0)
     with pytest.raises(ValueError, match="s_max"):
         dst.import_slot(d, kv, length)
+    assert not dst.page_tables[d] and int(dst.lengths[d]) == 0
 
 
 # ---------------------------------------------------------------------------
@@ -314,12 +318,11 @@ def test_cluster_token_parity_with_single_engine(stack):
 
 @pytest.mark.slow
 def test_paged_cluster_parity_and_handoff_dedup(stack):
-    """Acceptance: a 1-prefill + 1-decode cluster on the PAGED pool is
-    token-for-token identical to a collocated engine decoding on the
-    DENSE pool (the paged layout and the page-granular handoff are pure
-    optimizations), and with repeated questions the handoff ships fewer
-    bytes than the dense whole-prefix export -- pages the decode pool
-    already caches are referenced, not transferred."""
+    """Acceptance: a 1-prefill + 1-decode cluster is token-for-token
+    identical to a collocated engine (the page-granular handoff is a pure
+    optimization), and with repeated questions the handoff ships fewer
+    bytes than the whole prefix -- pages the decode pool already caches
+    are referenced, not transferred."""
     from repro.serving.engine import EngineConfig, RAGEngine
     from repro.serving.server import RAGServer
     gen, enc, corpus, make_q = stack
@@ -329,8 +332,7 @@ def test_paged_cluster_parity_and_handoff_dedup(stack):
     popular = [make_q(0), make_q(1)]
     questions = [popular[i % 2] for i in range(6)]
 
-    ref = RAGServer(RAGEngine(gen, enc, corpus,
-                              EngineConfig(paged=False, **kw)))
+    ref = RAGServer(RAGEngine(gen, enc, corpus, EngineConfig(**kw)))
     ref_handles = [ref.submit(q.copy()) for q in questions]
     ref.run_until_idle()
 
@@ -343,7 +345,7 @@ def test_paged_cluster_parity_and_handoff_dedup(stack):
     assert all(h.state is State.DONE for h in clu_handles)
     m = srv.cluster.metrics
     assert m["handoffs"] == len(questions)
-    # page-granular dedup: repeats shipped less than the dense payload
+    # page-granular dedup: repeats shipped less than the whole prefix
     assert m["handoff_pages_shared"] > 0
     assert 0 < m["handoff_bytes"] < m["handoff_bytes_full"]
     assert m["handoff_pages"] > 0

@@ -24,7 +24,7 @@ import pytest
 from repro.models import transformer as tr
 from repro.serving.faults import (CHAOS_SCHEDULES, EngineHealth, FaultInjector,
                                   FaultPlan, FaultSpec)
-from repro.serving.kv_cache import KVCachePool, payload_checksum
+from repro.serving.kv_cache import PagedKVCachePool, payload_checksum
 from repro.serving.request import (LEGAL_TRANSITIONS, TERMINAL_STATES,
                                    Request, State)
 
@@ -74,10 +74,9 @@ def test_injector_is_deterministic_across_runs():
     def run(inj):
         fired = [bool(inj.fire("decode_crash", engine=i % 2))
                  for i in range(6)]
-        payload = {"k": np.zeros((2, 4), np.float32),
-                   "v": np.zeros((2, 4), np.float32)}
+        payload, _ = _exported_prefix()
         inj.corrupt(payload)
-        return fired, payload["k"].copy()
+        return fired, np.asarray(payload.pages[0]["k"]).copy()
 
     plan = CHAOS_SCHEDULES["decode_crash"]
     a = run(FaultInjector(FaultPlan.from_schedule(plan, seed=11)))
@@ -101,7 +100,7 @@ def _tiny_cfg():
 def _exported_prefix(prefix_len=11):
     import jax.numpy as jnp
     cfg = _tiny_cfg()
-    pool = KVCachePool(cfg, n_slots=2, s_max=16)
+    pool = PagedKVCachePool(cfg, n_slots=2, s_max=16, page_size=8)
     rng = np.random.default_rng(0)
     cache = {k: jnp.asarray(rng.standard_normal(
         (cfg.n_layers, 1, prefix_len, cfg.n_kv_heads, cfg.d_head)),
@@ -123,10 +122,11 @@ def test_checksum_stable_and_detects_corruption():
 
 
 def test_checksum_detects_corruption_dense_payload():
-    kv = {"k": np.ones((2, 1, 8, 2, 4), np.float32),
-          "v": np.ones((2, 1, 8, 2, 4), np.float32)}
+    """A payload that lost a page on the wire no longer checks out."""
+    kv, _ = _exported_prefix()
+    assert len(kv.pages) == 2
     before = payload_checksum(kv)
-    FaultInjector(FaultPlan(seed=0)).corrupt(kv)
+    del kv.pages[1]
     assert payload_checksum(kv) != before
 
 

@@ -325,8 +325,9 @@ ENG_VOCAB = 128
 
 def test_engine_config_attn_validation():
     from repro.serving.engine import EngineConfig
-    with pytest.raises(ValueError, match="attn_impl"):
-        EngineConfig(attn_impl="fancy")
+    for impl in ("fancy", "splitk"):
+        with pytest.raises(ValueError, match="attn_impl"):
+            EngineConfig(attn_impl=impl)
     with pytest.raises(ValueError, match="attn_num_buffers"):
         EngineConfig(attn_num_buffers=1)
     assert EngineConfig().attn_impl == "auto"
@@ -407,15 +408,13 @@ def _steer_by_ref(engine, gaps, margins):
 
 # Decode-logit tolerance against the reference path.  The reference casts
 # its f32 softmax probabilities to bf16 before the PV product; the Pallas
-# kernel keeps them in f32 through an online softmax, and split-K
-# normalizes its bf16 partial outputs after the PV product.  The rounding
-# gap reaches the logits as a few bf16 ulps of their ~O(1) magnitude --
+# kernel keeps them in f32 through an online softmax.  The rounding gap
+# reaches the logits as a few bf16 ulps of their ~O(1) magnitude --
 # enough to flip a near-tie argmax on random weights: on the iterative
-# preset pallas, quad-buffered pallas and splitk all emit 52 where the
+# preset pallas and quad-buffered pallas both emit 52 where the
 # reference emits 51 (request 2, token 2), so the reference's greedy
 # tokens cannot be their gate there.  Worst readings on these stacks
-# (baseline / iterative): gap 0.039 / 0.047 pallas, 0.041 / 0.051 splitk,
-# margin 0 everywhere.  A planted kernel fault (each KV head reading its
+# (baseline / iterative): gap 0.039 / 0.047, margin 0 everywhere.  A planted kernel fault (each KV head reading its
 # neighbour's lanes) reads gap 4.7 / 5.3 and margin 5.4 / 4.8.  If every
 # logit is within LOGIT_ATOL, the impl's argmax sits at most 2*LOGIT_ATOL
 # below the reference's top logit, which bounds the margin.
@@ -433,9 +432,9 @@ def test_attn_impl_token_parity(stack, kw):
     quad-buffered Pallas runs emit identical token streams (staging depth
     never changes values), and on the baseline preset the same tokens as
     the gather+softmax reference.  Along the reference trajectory, the
-    Pallas kernel's and the split-K path's decode logits match the
-    reference within ``LOGIT_ATOL``, and the token each engine's own fused
-    program picks is within the matching margin of the reference's."""
+    Pallas kernel's decode logits match the reference within
+    ``LOGIT_ATOL``, and the token the engine's own fused program picks is
+    within the matching margin of the reference's."""
     _, _, _, make_q = stack
     questions = [make_q(i % 4) for i in range(5)]
     out_ref, eng_ref = _run(stack, {"attn_impl": "ref"}, kw, questions)
@@ -447,14 +446,12 @@ def test_attn_impl_token_parity(stack, kw):
         assert out_pal == out_ref
     assert eng_ref.metrics_snapshot()["attn_impl"] == "ref"
     assert eng_pal.metrics_snapshot()["attn_impl"] == "pallas"
-    for impl in ("pallas", "splitk"):
-        gaps, margins = [], []
-        _, eng = _run(stack, {"attn_impl": impl}, kw, questions,
-                      hook=lambda e: _steer_by_ref(  # noqa: B023
-                          e, gaps, margins))
-        assert gaps and max(gaps) <= LOGIT_ATOL, (impl, max(gaps))
-        assert max(margins) <= 2 * LOGIT_ATOL, (impl, max(margins))
-        assert eng.metrics_snapshot()["attn_impl"] == impl
+    gaps, margins = [], []
+    _, eng = _run(stack, {"attn_impl": "pallas"}, kw, questions,
+                  hook=lambda e: _steer_by_ref(e, gaps, margins))
+    assert gaps and max(gaps) <= LOGIT_ATOL, max(gaps)
+    assert max(margins) <= 2 * LOGIT_ATOL, max(margins)
+    assert eng.metrics_snapshot()["attn_impl"] == "pallas"
 
 
 @pytest.mark.slow

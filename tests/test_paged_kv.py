@@ -11,12 +11,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _oracle import assert_follows_forward, spy_appends
 
 from repro.models import transformer as tr
 from repro.serving.engine import EngineConfig
-from repro.serving.kv_cache import (ImportStats, KVCachePool,
-                                    PagedKVCachePool, PagedPrefix,
-                                    payload_nbytes)
+from repro.serving.kv_cache import (ImportStats, PagedKVCachePool,
+                                    PagedPrefix, payload_nbytes)
 from repro.serving.request import Request, State
 
 VOCAB = 64
@@ -38,8 +38,8 @@ def _rand_cache(cfg, p, seed=0):
 
 
 def _slot_contents(pool: PagedKVCachePool, slot: int) -> dict:
-    """Assemble a slot's logical prefix {"k","v"}: (L, length, H, D) from
-    its page table -- the paged analogue of slicing a dense slot row."""
+    """Assemble a slot's logical prefix {"k","v"}: (L, length, H_kv*D)
+    from its page table."""
     length = int(pool.lengths[slot])
     ps = pool.page_size
     out = {}
@@ -57,8 +57,7 @@ def _slot_contents(pool: PagedKVCachePool, slot: int) -> dict:
 
 def test_paged_export_import_bit_exact():
     """A prefix written into a paged pool, exported page-by-page, and
-    imported into another paged pool is bit-identical -- same contract as
-    the dense pool's handoff, now at page granularity."""
+    imported into another paged pool is bit-identical."""
     cfg = _tiny_cfg()
     src = PagedKVCachePool(cfg, n_slots=2, s_max=32, page_size=16)
     dst = PagedKVCachePool(cfg, n_slots=2, s_max=32, page_size=16)
@@ -75,12 +74,10 @@ def test_paged_export_import_bit_exact():
     assert kv.pages[0]["k"].shape == (cfg.n_layers, 16, cfg.n_kv_heads,
                                       cfg.d_head)
     assert kv.pages[1]["k"].shape[1] == p - 16
-    # the payload is exactly what a dense whole-prefix export would ship
-    dense = KVCachePool(cfg, n_slots=1, s_max=32)
-    ds = dense.alloc(rid=0)
-    dense.write_prefix(ds, cache, p)
-    dense_kv, _ = dense.export_slot(ds)
-    assert payload_nbytes(kv) == KVCachePool.handoff_bytes(dense_kv)
+    # the payload is the whole prefix: K and V, L * length * H_kv*D each
+    itemsize = np.dtype(jnp.bfloat16).itemsize
+    assert payload_nbytes(kv) == 2 * (cfg.n_layers * p * cfg.n_kv_heads
+                                      * cfg.d_head * itemsize)
 
     dslot = dst.alloc(rid=0)
     stats = dst.import_slot(dslot, kv, length)
@@ -126,7 +123,7 @@ def test_import_rejects_layout_mismatches():
     src.write_prefix(slot, _rand_cache(cfg, 40, seed=3), 40,
                      tokens=np.arange(40, dtype=np.int32))
     kv, length = src.export_slot(slot)
-    # a dense payload is not importable into a paged pool
+    # only a PagedPrefix is importable
     dst = PagedKVCachePool(cfg, n_slots=1, s_max=48, page_size=16)
     with pytest.raises(TypeError, match="PagedPrefix"):
         dst.import_slot(dst.alloc(0), {"k": np.zeros(1), "v": np.zeros(1)}, 1)
@@ -247,20 +244,23 @@ def test_pool_capacity_invariants():
         pool.prepare_append(s, 1)            # no room to stage a write
     with pytest.raises(AssertionError, match="s_max"):
         pool.advance([s])                    # ...nor to advance past the end
-    dense = KVCachePool(cfg, n_slots=1, s_max=16)
-    d = dense.alloc(0)
-    dense.write_prefix(d, _rand_cache(cfg, 16, seed=11), 16)
+    # every slot taken: alloc refuses; a slot freed and taken again starts
+    # empty, and a write range past s_max is refused before any page is
+    # allocated for it
+    assert pool.alloc(1) is None
+    pool.release(s)
+    assert pool.alloc(1) == s
+    assert int(pool.lengths[s]) == 0 and not pool.page_tables[s]
     with pytest.raises(AssertionError, match="s_max"):
-        dense.advance([d])
+        pool.prepare_append(s, 17)
+    assert not pool.page_tables[s]
 
 
-@pytest.mark.parametrize("kind", ["paged", "dense"])
-def test_positions_are_a_snapshot(kind):
+def test_positions_are_a_snapshot():
     """``positions()`` feeds a step that may still be running when the host
     calls ``advance``; the array it returned must keep its values."""
     cfg = _tiny_cfg()
-    pool = (PagedKVCachePool(cfg, n_slots=2, s_max=16, page_size=8)
-            if kind == "paged" else KVCachePool(cfg, n_slots=2, s_max=16))
+    pool = PagedKVCachePool(cfg, n_slots=2, s_max=16, page_size=8)
     s = pool.alloc(0)
     pool.write_prefix(s, _rand_cache(cfg, 5, seed=3), 5)
     pos = pool.positions()
@@ -326,10 +326,11 @@ def test_engine_config_validation():
         EngineConfig(iter_query_tokens=0)
     with pytest.raises(ValueError, match="prefill_chunk"):
         EngineConfig(prefill_chunk=0)
-    with pytest.raises(ValueError, match="paged"):
-        EngineConfig(prefill_chunk=8, fused_decode=False)
-    # the pre-fusion parity path implies the dense pool
-    assert EngineConfig(fused_decode=False).paged is False
+    EngineConfig(prefill_chunk=8)
+    # one pool and one decode path: the dense-pool knobs are gone
+    for knob in ("paged", "fused_decode"):
+        with pytest.raises(TypeError, match=knob):
+            EngineConfig(**{knob: False})
 
 
 # ---------------------------------------------------------------------------
@@ -372,30 +373,21 @@ def _engine(stack, **kw):
     {"iterative_interval": 3, "retrieval_batch": 2,
      "max_new_tokens": 9},                                 # iterative preset
 ], ids=["baseline", "iterative"])
-def test_paged_vs_dense_token_parity(stack, kw):
-    """The paged pool is a pure storage-layout change: token-for-token
-    identical to the dense fused path on both the baseline and the
+def test_engine_tokens_follow_forward(stack, kw):
+    """The paged engine's tokens are the teacher-forced forward's greedy
+    choices over each request's logical sequence, on the baseline and the
     iterative-retrieval configurations."""
-    _, _, _, make_q = stack
-    questions = [make_q(i % 4) for i in range(5)]
-
-    def run(paged):
-        engine = _engine(stack, paged=paged, **kw)
-        assert isinstance(engine.pool, PagedKVCachePool) is paged
-        reqs = [Request(question=q.copy()) for q in questions]
-        engine.serve(reqs)
-        assert all(r.state is State.DONE for r in reqs)
-        return [r.output for r in reqs], engine.metrics_snapshot()
-
-    out_paged, m_paged = run(True)
-    out_dense, m_dense = run(False)
-    assert out_paged == out_dense
-    assert m_paged["pages_allocated"] > 0
-    assert m_paged["capacity_stops"] == 0
-    # fused-path hot-loop guarantees carry over to the paged kernels
-    assert m_paged["cache_copy_bytes"] == 0
-    assert 0 < m_paged["decode_host_syncs"] <= m_paged["decode_steps"]
-    assert "pages_allocated" not in m_dense
+    gen, _, _, make_q = stack
+    engine = _engine(stack, **kw)
+    appends = spy_appends(engine)
+    reqs = [Request(question=make_q(i % 4)) for i in range(5)]
+    engine.serve(reqs)
+    assert all(r.state is State.DONE for r in reqs)
+    assert_follows_forward(gen, reqs, appends)
+    m = engine.metrics_snapshot()
+    assert m["pages_allocated"] > 0
+    assert m["capacity_stops"] == 0
+    assert 0 < m["decode_host_syncs"] <= m["decode_steps"]
 
 
 @pytest.mark.slow

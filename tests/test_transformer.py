@@ -1,5 +1,8 @@
 """Transformer model-zoo unit tests: parity, MoE, quantization."""
 
+from dataclasses import replace
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -37,12 +40,40 @@ def test_forward_shapes_no_nan(params):
     assert not bool(jnp.isnan(logits).any())
 
 
+def _pool(cfg, n_pages, page, kv=None, tables=None):
+    """A bf16 page pool; with ``kv`` (forward's (L, B, T, H, D) K/V), each
+    sequence b's positions laid into the pages ``tables[b]``."""
+    row = cfg.n_kv_heads * cfg.d_head
+    pool = {k: jnp.zeros((cfg.n_layers, n_pages, page, row), jnp.bfloat16)
+            for k in ("k", "v")}
+    if kv is None:
+        return pool
+    B, M = tables.shape
+    L, _, T = kv["k"].shape[:3]
+    pad = ((0, 0), (0, 0), (0, M * page - T), (0, 0), (0, 0))
+    return {k: pool[k].at[:, np.asarray(tables).reshape(-1)].set(
+                jnp.pad(kv[k], pad).reshape(L, B * M, page, row))
+            for k in pool}
+
+
+def _rows(cfg, pool, block_row, n):
+    """Positions [0, n) of one sequence's pages: (L, n, H_kv*D) float32."""
+    L, _, page, row = pool.shape
+    return np.asarray(pool[:, np.asarray(block_row)].reshape(
+        L, -1, row)[:, :n], np.float32)
+
+
 def test_decode_matches_forward_exactly(params):
+    """A paged decode step after a forward prefill reproduces the
+    forward's last-position logits bit for bit."""
     toks = _toks(2, 12)
     full, _ = tr.forward(params, toks, TINY)
-    _, cache = tr.prefill(params, toks[:, :-1], TINY, cache_len=16)
-    step_logits, _ = tr.decode_step(params, cache, toks[:, -1],
-                                    jnp.full((2,), 11, jnp.int32), TINY)
+    _, _, kv = tr.forward(params, toks[:, :-1], TINY, collect_cache=True)
+    tables = np.random.default_rng(0).permutation(8).reshape(2, 4)
+    pool = _pool(TINY, 9, 4, kv, tables)
+    step_logits, _ = tr.paged_decode_step(
+        params, pool, toks[:, -1], jnp.full((2,), 11, jnp.int32),
+        jnp.asarray(tables, jnp.int32), TINY)
     np.testing.assert_allclose(np.asarray(full[:, -1]),
                                np.asarray(step_logits), rtol=0, atol=0)
 
@@ -50,12 +81,14 @@ def test_decode_matches_forward_exactly(params):
 def test_multi_step_decode_matches_forward(params):
     toks = _toks(1, 10)
     full, _ = tr.forward(params, toks, TINY)
-    _, cache = tr.prefill(params, toks[:, :4], TINY, cache_len=16)
+    _, _, kv = tr.forward(params, toks[:, :4], TINY, collect_cache=True)
+    tables = np.asarray([[2, 0, 3, 1]])
+    pool = _pool(TINY, 5, 4, kv, tables)
+    step = jax.jit(partial(tr.paged_decode_step, cfg=TINY))
     for i in range(4, 10):
-        logits, cache = tr.decode_step(params, cache, toks[:, i - 1] * 0
-                                       + toks[:, i - 1],
-                                       jnp.full((1,), i - 1, jnp.int32),
-                                       TINY)
+        logits, pool = step(
+            params, pool, toks[:, i - 1], jnp.full((1,), i - 1, jnp.int32),
+            jnp.asarray(tables, jnp.int32))
         # feed true token: logits must match teacher-forced forward at i-1
         np.testing.assert_allclose(np.asarray(full[:, i - 1]),
                                    np.asarray(logits), atol=1e-2)
@@ -157,38 +190,120 @@ def test_rope_partial_fraction():
 
 
 def test_chunk_extend_matches_sequential_decode(params):
-    """Bucketed cache append == feeding the tokens one decode step at a
-    time (the engine's pre-batching iteration-prefill semantics), with pad
-    rows dropped and other slots untouched."""
-    n_slots, s_max, slot, plen = 3, 32, 1, 5
-    cache = tr.make_cache(TINY, n_slots, s_max)
-    _, _, pc = tr.forward(params, _toks(1, plen), TINY, collect_cache=True)
-    cache = {k: cache[k].at[:, slot, :plen].set(pc[k][:, 0]) for k in cache}
+    """Bucketed paged chunk extend == feeding the tokens one paged decode
+    step at a time, with pad rows dropped and other sequences' pages
+    untouched."""
+    page, m, slot, plen = 4, 4, 1, 5
+    tables = np.arange(12).reshape(3, m)[[2, 0, 1]]
+    _, _, kv = tr.forward(params, _toks(1, plen), TINY, collect_cache=True)
+    pool = _pool(TINY, 13, page, kv, tables[slot:slot + 1])
     tokens = np.asarray([7, 11, 3, 9, 22], np.int32)
 
-    seq = dict(cache)
+    seq = dict(pool)
+    mask = jnp.asarray(np.arange(3) == slot)
+    step = jax.jit(partial(tr.paged_decode_step, cfg=TINY))
     for i, t in enumerate(tokens):
-        tv = np.zeros(n_slots, np.int32)
-        tv[slot] = t
-        ps = np.zeros(n_slots, np.int32)
-        ps[slot] = plen + i
-        _, new = tr.decode_step(params, seq, jnp.asarray(tv),
-                                jnp.asarray(ps), TINY)
-        seq = jax.tree_util.tree_map(
-            lambda n_, o: o.at[:, slot].set(n_[:, slot]), new, seq)
+        step_logits, seq = step(
+            params, seq, jnp.full((3,), t, jnp.int32),
+            jnp.full((3,), plen + i, jnp.int32), jnp.asarray(tables),
+            write_mask=mask)
 
     padded = np.zeros(8, np.int32)           # bucket 8 > 5 valid tokens
     padded[:len(tokens)] = tokens
-    chunk = tr.chunk_extend(params, cache, jnp.int32(slot),
-                            jnp.asarray(padded), jnp.int32(plen),
-                            jnp.int32(len(tokens)), TINY)
+    chunk, logits = tr.paged_chunk_extend(
+        params, pool, jnp.asarray(tables[slot]), jnp.asarray(padded),
+        jnp.int32(plen), jnp.int32(len(tokens)), TINY)
     end = plen + len(tokens)
+    np.testing.assert_allclose(np.asarray(logits, np.float32),
+                               np.asarray(step_logits[slot], np.float32),
+                               atol=5e-2)
     for k in ("k", "v"):
-        np.testing.assert_allclose(
-            np.asarray(chunk[k][:, slot, :end], np.float32),
-            np.asarray(seq[k][:, slot, :end], np.float32),
-            rtol=2e-2, atol=2e-2)
-        # pad rows were dropped, untouched slots stayed zero
-        assert float(jnp.abs(chunk[k][:, slot, end:]).max()) == 0.0
-        assert float(jnp.abs(chunk[k][:, 0]).max()) == 0.0
-        assert float(jnp.abs(chunk[k][:, 2]).max()) == 0.0
+        np.testing.assert_allclose(_rows(TINY, chunk[k], tables[slot], end),
+                                   _rows(TINY, seq[k], tables[slot], end),
+                                   rtol=2e-2, atol=2e-2)
+        # pad rows were dropped, other sequences' pages stayed zero
+        rest = _rows(TINY, chunk[k], tables[slot], m * page)[:, end:]
+        assert float(np.abs(rest).max()) == 0.0
+        for other in (0, 2):
+            assert float(jnp.abs(chunk[k][:, tables[other]]).max()) == 0.0
+
+
+# The fold's safety net: the serving entry points share one layer body and
+# differ in how they write and attend, so for one token sequence every
+# route through them must give forward's logits and forward's K/V rows.
+# MoE runs with capacity_factor = n_experts / top_k, where moe_ffn drops
+# no token at any sequence length.
+ROUTE_CFGS = {
+    "dense": TINY,
+    "moe": replace(TINY_MOE, moe=tr.MoEConfig(n_experts=8, top_k=2,
+                                               capacity_factor=4.0)),
+    "rotary_half": replace(TINY, name="rh", rotary_frac=0.5),
+    "gqa_g4": replace(TINY, name="g4", n_heads=8, n_kv_heads=2),
+}
+# bf16 tolerances: about one bf16 ulp of the logits' and K/V's O(1)
+# magnitudes.  On the CPU every route reads a gap of exactly 0.
+ROUTE_ATOL = 0.02
+KV_ATOL = 0.02
+
+
+@pytest.mark.parametrize("name", list(ROUTE_CFGS))
+def test_entry_points_agree(name):
+    """Three routes over one sequence agree within bf16 tolerance, on the
+    logits at every position and on the K/V rows they write: ``forward``
+    teacher-forced; ``paged_chunk_extend`` of the prompt followed by
+    ``paged_decode_step`` calls; and single-token ``paged_decode_step``
+    calls from position 0."""
+    cfg = ROUTE_CFGS[name]
+    params = tr.init_params(jax.random.PRNGKey(0), cfg)
+    S, p, page = 14, 6, 4
+    toks = _toks(1, S, cfg.vocab_size, seed=3)
+    full, _, kv = tr.forward(params, toks, cfg, collect_cache=True)
+    full = np.asarray(full[0, :, :cfg.vocab_size], np.float32)
+    want_kv = {k: np.asarray(v[:, 0], np.float32).reshape(cfg.n_layers, S, -1)
+               for k, v in kv.items()}
+    tables = jnp.asarray([[3, 0, 5, 1]], jnp.int32)     # 16 rows >= S
+    step = jax.jit(partial(tr.paged_decode_step, cfg=cfg))
+
+    def decode(pool, start, out):
+        for i in range(start, S):
+            lg, pool = step(params, pool, toks[:, i],
+                            jnp.asarray([i], jnp.int32), tables)
+            out.append(np.asarray(lg[0, :cfg.vocab_size], np.float32))
+        return np.stack(out), pool
+
+    padded = jnp.zeros(8, jnp.int32).at[:p].set(toks[0, :p])
+    chunked, last = jax.jit(partial(tr.paged_chunk_extend, cfg=cfg))(
+        params, _pool(cfg, 6, page), tables[0], padded, jnp.int32(0),
+        jnp.int32(p))
+    routes = {
+        "chunk+decode": decode(
+            chunked, p, [np.asarray(last[:cfg.vocab_size], np.float32)])
+        + (full[p - 1:],),
+        "decode": decode(_pool(cfg, 6, page), 0, []) + (full,),
+    }
+    for route, (got, pool, want) in routes.items():
+        gap = np.abs(got - want).max()
+        assert gap <= ROUTE_ATOL, (route, gap)
+        for k in ("k", "v"):
+            rows = _rows(cfg, pool[k], tables[0], S)
+            np.testing.assert_allclose(rows, want_kv[k], rtol=0,
+                                       atol=KV_ATOL, err_msg=route)
+
+
+def test_greedy_generate_matches_teacher_forced_forward(params):
+    """Batched greedy generation over right-padded prompts of ragged
+    lengths: every generated token is the teacher-forced forward's greedy
+    choice within the oracle's gap."""
+    from _oracle import greedy_gaps
+    lengths = np.asarray([3, 8, 5], np.int32)
+    prompts = np.asarray(_toks(3, 8, seed=4))
+    n_new = 6
+    out = np.asarray(jax.jit(lambda t, n: tr.greedy_generate(
+        params, t, n, TINY, n_new))(jnp.asarray(prompts),
+                                    jnp.asarray(lengths)))
+    assert out.shape == (3, n_new)
+    for b, n in enumerate(lengths):
+        seq = np.concatenate([prompts[b, :n], out[b, :-1]])
+        gaps = greedy_gaps(params, TINY, seq, np.arange(n - 1, len(seq)),
+                           out[b])
+        assert gaps.max() <= 0.02, (b, gaps)
