@@ -2,10 +2,14 @@
 
 The public contract matches ``transformer.paged_decode_step``'s
 block-table-native ``attn_impl`` signature: q for one decode token,
-the POST-SCATTER page pool in page-row layout (P, page, H_kv*D), the dense
-block tables and the per-sequence cache lengths.  No logical-view gather
-and no pool reshape happens anywhere on this path -- the kernel walks the
-pool through the block table directly.
+the POST-SCATTER page pool in page-row layout, the dense block tables and
+the per-sequence cache lengths.  From the decode step, the pool is the
+flat (L*P, page, H_kv*D) view of every layer's pages and the tables are
+offset to the current layer (``block_tables + l*P``); any
+(P, page, H_kv*D) pool with tables into it works the same.  No
+logical-view gather, no per-layer slice and no cast of the pool happens
+anywhere on this path -- the kernel DMAs the live pages from HBM through
+the block table and casts each fetched tile to the query's dtype.
 """
 
 from __future__ import annotations

@@ -42,11 +42,14 @@ q[h, g] in lanes h*D:(h+1)*D and zeros elsewhere.  Then
 That is H_kv times the MXU work of per-head products, traded for one
 lane-dense product per block in place of 2*H_kv tiny ones per page.
 
-Precision.  q and K reach the MXU in their own dtype (bf16 when serving)
+Precision.  Each fetched K and V tile is cast to the query's dtype, so
+the pool may be wider than the compute dtype without a cast of the whole
+pool: q and K reach the MXU in the query's dtype (bf16 when serving)
 with f32 accumulation -- a product of two bf16 values is exact in f32 --
 and ``sm_scale`` is applied to the f32 scores.  Scores, the running max
-and sum, the probabilities p and the accumulator are f32; V is cast to
-f32 for p . V.  An f32 pool runs the same code in f32.
+and sum, the probabilities p and the accumulator are f32; V, in the
+query's dtype, is widened to f32 for p . V.  An f32 query runs the same
+code in f32.
 
 The pool is passed as ``memory_space=ANY`` (stays in HBM); only the
 staging buffers live in VMEM.  CPU CI runs the same kernel in interpret
@@ -164,8 +167,8 @@ def _paged_decode_kernel(tables_ref, len_ref,           # scalar prefetch
         def _start():                                   # noqa: B023
             start_block(t, t)
 
-    cdt = jnp.promote_types(q_ref.dtype, kbuf.dtype)
-    q_bd = block_diagonal_query(q_ref[0].astype(cdt), h_kv)  # (H, row)
+    cdt = q_ref.dtype
+    q_bd = block_diagonal_query(q_ref[0], h_kv)          # (H, row)
     h = q_bd.shape[0]
 
     def body(t, carry):
@@ -180,7 +183,7 @@ def _paged_decode_kernel(tables_ref, len_ref,           # scalar prefetch
 
         wait_block(t, slot)
         k = kbuf[slot].astype(cdt)                      # (block, row)
-        v = vbuf[slot].astype(jnp.float32)
+        v = vbuf[slot].astype(cdt).astype(jnp.float32)
         s = jax.lax.dot_general(q_bd, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale                                # (H, block)

@@ -64,13 +64,12 @@ def paged_decode_attention_ref(q: jax.Array, k_pages: jax.Array,
     ppb = pages_per_block(page, m_pages)
     bk = ppb * page
     sm_scale = 1.0 / math.sqrt(d)
-    cdt = jnp.promote_types(q.dtype, k_pages.dtype)
+    cdt = q.dtype
     length = jnp.minimum(lengths.astype(jnp.int32), m_pages * page)
     n_pages = (length + page - 1) // page
     cells = []
     for bi in range(b):
-        q_bd = block_diagonal_query(
-            q[bi].reshape(h_kv * g, d).astype(cdt), h_kv)
+        q_bd = block_diagonal_query(q[bi].reshape(h_kv * g, d), h_kv)
         m_run = jnp.full((h_kv * g, 1), NEG_INF, jnp.float32)
         l_run = jnp.zeros((h_kv * g, 1), jnp.float32)
         acc = jnp.zeros((h_kv * g, row), jnp.float32)
@@ -81,7 +80,7 @@ def paged_decode_attention_ref(q: jax.Array, k_pages: jax.Array,
             k = jnp.where(live, k_pages[phys], 0).reshape(bk, row)
             v = jnp.where(live, v_pages[phys], 0).reshape(bk, row)
             k = k.astype(cdt)
-            v = v.astype(jnp.float32)
+            v = v.astype(cdt).astype(jnp.float32)
             s = jax.lax.dot_general(q_bd, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             s = s * sm_scale

@@ -472,11 +472,12 @@ def greedy_generate(params: dict, tokens: jax.Array, lengths: jax.Array,
 # (``repro.serving.kv_cache.PagedKVCachePool``); these entry points only
 # scatter new K/V into physical rows and hand the POST-SCATTER pool plus the
 # block tables to a block-table-native attention impl
-# (``attn(q, k_pages, v_pages, block_tables, cache_len)``).  The default
-# impl gathers the logical view (B, M*page, H, D) and runs reference masked
-# softmax, which zeroes every stale physical row exactly.  The Pallas
-# kernel (``repro.kernels.paged_attention``) honors the same contract
-# without ever materializing the gather.
+# (``attn(q, k_pages, v_pages, block_tables, cache_len)``; the decode step
+# hands it every layer's pages as one flat pool, with the tables offset to
+# the layer).  The default impl gathers the logical view (B, M*page, H, D)
+# and runs reference masked softmax, which zeroes every stale physical row
+# exactly.  The Pallas kernel (``repro.kernels.paged_attention``) honors
+# the same contract without ever materializing the gather.
 
 
 def make_paged_cache(cfg: TransformerConfig, n_pages: int, page_size: int,
@@ -501,9 +502,16 @@ def paged_decode_step(params: dict, cache: dict, token: jax.Array,
     touched for them.
 
     ``attn_impl(q, k_pages, v_pages, block_tables, cache_len)`` is
-    BLOCK-TABLE-NATIVE: it receives the post-scatter page pool
-    (P, page, H_kv*D) and the tables, not a gathered per-sequence view,
-    so a paged kernel can walk the pool directly.  The default impl
+    BLOCK-TABLE-NATIVE: at layer l it receives the WHOLE post-scatter
+    pool viewed as (L*P, page, H_kv*D) -- a reshape, which XLA lowers to
+    a bitcast of the in-place scatter's output -- and the tables offset
+    to layer l (``block_tables + l*P``), not a gathered per-sequence view
+    and not a per-layer slice (which XLA would read whole from HBM into
+    VMEM every layer of every step), so a paged kernel DMAs only the
+    live pages straight from HBM.  Unallocated table entries are 0 and
+    map to page 0 of layer l, inside the pool: the kernel never fetches
+    a page past a sequence's length and the default impl masks it by
+    position, so no offset entry points past ``L*P``.  The default impl
     reproduces the pre-kernel path bit-for-bit: gather the logical
     (B, M*page, H, D) view, repeat KV heads, reference masked softmax.
     Non-stepping rows read the same pool bytes either way (their write
@@ -511,7 +519,9 @@ def paged_decode_step(params: dict, cache: dict, token: jax.Array,
 
     The pool is carried through the layer scan and written in place, one
     row per stepping slot and layer; it keeps its own dtype (the new K/V
-    is cast to it, the layer's pages to ``compute_dtype`` for attention).
+    is cast to it).  Only what attention reads is cast to
+    ``compute_dtype``: the default impl's gathered view, and in the
+    kernel each fetched tile (to the query's dtype) -- never the pool.
     """
     B = token.shape[0]
     L, P, page, row = cache["k"].shape
@@ -533,8 +543,10 @@ def paged_decode_step(params: dict, cache: dict, token: jax.Array,
     if attn is None:
         def attn(q, kp, vp, tables, cache_len):
             # gather each sequence's logical view: (B, M*page, H, D)
-            kg = kp[tables].reshape(B, M * page, cfg.n_kv_heads, cfg.d_head)
-            vg = vp[tables].reshape(B, M * page, cfg.n_kv_heads, cfg.d_head)
+            kg = kp[tables].reshape(B, M * page, cfg.n_kv_heads,
+                                    cfg.d_head).astype(compute_dtype)
+            vg = vp[tables].reshape(B, M * page, cfg.n_kv_heads,
+                                    cfg.d_head).astype(compute_dtype)
             kr = cm.repeat_kv(kg, cfg.q_per_kv)
             vr = cm.repeat_kv(vg, cfg.q_per_kv)
             return cm.decode_attention_ref(q, kr, vr, cache_len)
@@ -549,12 +561,10 @@ def paged_decode_step(params: dict, cache: dict, token: jax.Array,
                     k.reshape(B, row).astype(k_pool.dtype), mode="drop")
                 vpool = v_pool.at[l, page_idx, page_row].set(
                     v.reshape(B, row).astype(v_pool.dtype), mode="drop")
-                kp = jax.lax.dynamic_index_in_dim(
-                    kpool, l, 0, keepdims=False).astype(compute_dtype)
-                vp = jax.lax.dynamic_index_in_dim(
-                    vpool, l, 0, keepdims=False).astype(compute_dtype)
+                kp = kpool.reshape(L * P, page, row)
+                vp = vpool.reshape(L * P, page, row)
             with jax.named_scope("attention"):
-                out = attn(q, kp, vp, block_tables, pos + 1)
+                out = attn(q, kp, vp, block_tables + l * P, pos + 1)
             return out, (kpool, vpool)
 
         x, _, (k_pool, v_pool) = _layer(x, lp, cfg, pos, attend,

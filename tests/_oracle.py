@@ -1,17 +1,24 @@
-"""Teacher-forced ``tr.forward`` as the oracle for greedy token streams.
+"""Oracles the tests hold the serving path to.
 
-A greedy token passes when its logit, in a forward pass over the logical
+Teacher-forced ``tr.forward`` is the oracle for greedy token streams.  A
+greedy token passes when its logit, in a forward pass over the logical
 sequence that produced it, lies within ``GAP`` of the best logit at that
 position.  That is robust to bf16 near-ties: a bucketed prefill, a paged
 gather or a chunked append may round differently from the monolithic
 forward and flip a tie, but never by more than a few bf16 ulps.
 
+:func:`sliced_paged_decode_step` is the oracle for one paged decode step:
+the route that hands the attention one layer's slice of the pool, cast to
+the compute dtype, with the plain block tables.
+
 Usage in test modules:  ``from _oracle import assert_follows_forward``
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models import common as cm
 from repro.models import transformer as tr
 
 GAP = 0.02
@@ -65,3 +72,59 @@ def assert_follows_forward(gen, reqs, appends=()) -> None:
         seq, at = logical_sequence(r, appends)
         gaps = greedy_gaps(gen.params, gen.cfg, seq, at, r.output)
         assert gaps.max() <= GAP, (r.rid, gaps)
+
+
+def sliced_paged_decode_step(params, cache, token, pos, block_tables, cfg,
+                             compute_dtype=jnp.bfloat16, attn_impl=None,
+                             write_mask=None):
+    """``tr.paged_decode_step`` by the per-layer-slice route: after the
+    layer's scatter, attention gets ``pool[l]`` cast to ``compute_dtype``
+    -- a (P, page, H_kv*D) pool -- and the tables as they are.  Same
+    arguments and results as the step under test."""
+    B = token.shape[0]
+    L, P, page, row = cache["k"].shape
+    M = block_tables.shape[1]
+    x = jnp.take(cm.maybe_dequant(params["embed"], compute_dtype), token,
+                 axis=0)[:, None, :]
+    page_log = pos // page
+    phys = jnp.take_along_axis(
+        block_tables, jnp.minimum(page_log, M - 1)[:, None], axis=1)[:, 0]
+    flat = jnp.where(page_log < M, phys * page + pos % page, P * page)
+    if write_mask is not None:
+        flat = jnp.where(write_mask, flat, P * page)
+    page_idx, page_row = flat // page, flat % page
+    attn = attn_impl
+    if attn is None:
+        def attn(q, kp, vp, tables, cache_len):
+            kg = kp[tables].reshape(B, M * page, cfg.n_kv_heads, cfg.d_head)
+            vg = vp[tables].reshape(B, M * page, cfg.n_kv_heads, cfg.d_head)
+            return cm.decode_attention_ref(
+                q, cm.repeat_kv(kg, cfg.q_per_kv),
+                cm.repeat_kv(vg, cfg.q_per_kv), cache_len)
+
+    def layer_fn(carry, scanned):
+        x, k_pool, v_pool = carry
+        lp, l = scanned
+
+        def attend(q, k, v):
+            kpool = k_pool.at[l, page_idx, page_row].set(
+                k.reshape(B, row).astype(k_pool.dtype), mode="drop")
+            vpool = v_pool.at[l, page_idx, page_row].set(
+                v.reshape(B, row).astype(v_pool.dtype), mode="drop")
+            kp = jax.lax.dynamic_index_in_dim(
+                kpool, l, 0, keepdims=False).astype(compute_dtype)
+            vp = jax.lax.dynamic_index_in_dim(
+                vpool, l, 0, keepdims=False).astype(compute_dtype)
+            return attn(q, kp, vp, block_tables, pos + 1), (kpool, vpool)
+
+        x, _, (k_pool, v_pool) = tr._layer(x, lp, cfg, pos, attend,
+                                           compute_dtype)
+        return (x, k_pool, v_pool), None
+
+    (x, k_pool, v_pool), _ = jax.lax.scan(
+        layer_fn, (x, cache["k"], cache["v"]),
+        (params["layers"], jnp.arange(L, dtype=jnp.int32)))
+    x = cm.rms_norm(x, params["ln_f"], cfg.norm_eps)
+    head = cm.maybe_dequant(params["head"], compute_dtype)
+    logits = (x.astype(compute_dtype) @ head)[:, 0]
+    return logits, {"k": k_pool, "v": v_pool}

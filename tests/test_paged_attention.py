@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _oracle import sliced_paged_decode_step
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.paged_attention.ops import paged_decode_attention
@@ -223,6 +224,40 @@ def test_pages_past_live_range_are_never_read(h_kv, g, d):
                           np.asarray(mirror, np.float32))
 
 
+@CELL_GEOMETRIES
+@pytest.mark.parametrize("layer", ["first", "last"])
+def test_flat_multilayer_pool_reads_only_its_layer(h_kv, g, d, layer):
+    """The decode step hands the kernel every layer's pages as one flat
+    (L*P, page, row) pool with the tables offset to layer l.  With every
+    other layer NaN, the run on the flat view is bit-identical to the run
+    on ``pool[l]`` with the plain tables, and to the mirror: one row's
+    table is full, another's ends in unallocated (0) entries, which the
+    offset maps to page 0 of layer l and the kernel never fetches."""
+    n_layers, page, m = 3, CELL_PAGE, CELL_M
+    lengths = [m * page, 2 * page + 3, 1]
+    q, k, v, tables, lens = _problem(len(lengths), h_kv, g, d, page, m,
+                                     lengths)
+    tables = np.array(tables)
+    tables[1, 3:] = 0
+    tables[2, 1:] = 0
+    tables = jnp.asarray(tables)
+    n_pages = k.shape[0]
+    l = 0 if layer == "first" else n_layers - 1
+    mine = (jnp.arange(n_layers) == l)[:, None, None, None]
+
+    def flat(pages):
+        stacked = jnp.where(mine, pages[None], jnp.nan).astype(pages.dtype)
+        return stacked.reshape(n_layers * n_pages, page, h_kv * d)
+
+    out = paged_decode_attention_pallas(
+        q, flat(k), flat(v), tables + l * n_pages, lens,
+        interpret=pltpu.InterpretParams())
+    one = _gate(q, k, v, tables, lens)
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    assert np.array_equal(np.asarray(out, np.float32),
+                          np.asarray(one, np.float32))
+
+
 def test_single_buffer_rejected():
     q, k, v, tables, lens = _problem(1, 1, 1, 8, 4, 2, [4])
     with pytest.raises(ValueError, match="num_buffers"):
@@ -314,6 +349,52 @@ def test_write_mask_rows_identical_under_kernel_attn():
     # path's bf16 softmax-probs cast)
     assert np.array_equal(np.argmax(np.asarray(log_ref), -1),
                           np.argmax(np.asarray(log_ker), -1))
+
+
+@pytest.mark.parametrize("attn", ["ref", "kernel"])
+@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16_pool", "f32_pool"])
+def test_decode_step_matches_per_layer_slice_oracle(attn, pool_dtype):
+    """One decode step over a three-layer pool, with the "ref" attention
+    and with the interpret-mode kernel, gives the same logits and the
+    same pool, bit for bit, as the per-layer-slice route: handing the
+    attention the whole pool and offset tables changes what is read
+    from, never what is computed.  A float32 pool under bf16 compute
+    checks that only the gathered view (ref) or each fetched tile
+    (kernel) is cast, to the values the slice's cast gave."""
+    cfg = tr.TransformerConfig(name="flat", n_layers=3, d_model=32,
+                               n_heads=4, n_kv_heads=2, d_head=16, d_ff=64,
+                               vocab_size=64)
+    params = tr.init_params(jax.random.PRNGKey(1), cfg)
+    page, m, b = 8, 4, 3
+    n_pages = b * m + 2
+    rng = np.random.default_rng(11)
+    cache = {kk: jnp.asarray(rng.standard_normal(
+        (cfg.n_layers, n_pages, page, cfg.n_kv_heads * cfg.d_head)),
+        pool_dtype) for kk in ("k", "v")}
+    tables = rng.permutation(n_pages)[:b * m].reshape(b, m)
+    tables[2, 2:] = 0                       # unallocated entries
+    tables = jnp.asarray(tables, jnp.int32)
+    token = jnp.asarray([3, 5, 7], jnp.int32)
+    pos = jnp.asarray([m * page - 1, 9, 12], jnp.int32)
+    mask = jnp.asarray([True, True, False])
+    impl = None
+    if attn == "kernel":
+        def impl(q, kp, vp, tbl, cache_len):
+            return paged_decode_attention(q, kp, vp, tbl, cache_len,
+                                          interpret=True)
+
+    log, out = tr.paged_decode_step(params, cache, token, pos, tables, cfg,
+                                    attn_impl=impl, write_mask=mask)
+    log_o, out_o = sliced_paged_decode_step(
+        params, cache, token, pos, tables, cfg, attn_impl=impl,
+        write_mask=mask)
+    assert np.array_equal(np.asarray(log, np.float32),
+                          np.asarray(log_o, np.float32))
+    for kk in ("k", "v"):
+        assert out[kk].dtype == pool_dtype
+        assert np.array_equal(np.asarray(out[kk], np.float32),
+                              np.asarray(out_o[kk], np.float32))
 
 
 # ---------------------------------------------------------------------------

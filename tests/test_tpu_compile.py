@@ -9,7 +9,8 @@ kernel (also at chatglm3-6b's head geometry), the IVF-PQ scan at the smoke
 run's shape, and the bucketed prefill, paged fused decode step and
 chunk-extend programs with bf16 parameters;
 and the decode step at the benchmark cells' shapes (granite-3-2b and
-chatglm3-6b), which must update the donated page pool in place.
+chatglm3-6b), which must update the donated page pool in place and hand
+the kernel the whole pool, never a per-layer slice of it.
 
 The topology is described inside a module-scoped fixture (never at import:
 only one process at a time may load the TPU library), and the persistent
@@ -195,21 +196,30 @@ def _nbytes(tree) -> int:
                for a in jax.tree_util.tree_leaves(tree))
 
 
-@pytest.mark.parametrize("cfg", [GRANITE, CHATGLM],
-                         ids=["granite-3-2b", "chatglm3-6b"])
+CELLS = pytest.mark.parametrize("cfg", [GRANITE, CHATGLM],
+                                ids=["granite-3-2b", "chatglm3-6b"])
+
+
+def _cell_decode(one_chip, cfg):
+    """The decode step compiled at a ``<model>.rag-decode`` benchmark
+    cell's shapes (32 slots, s_max 768, 16 spare pages: 1552 pages):
+    ``(compiled, pool)``."""
+    slots, s_max = 32, 768
+    params = _spec(tr.abstract_params(cfg, jnp.bfloat16), one_chip)
+    pool = _paged_pool(one_chip, slots, s_max, cfg)
+    return _compile_paged_decode(one_chip, params, pool, slots, s_max,
+                                 cfg), pool
+
+
+@CELLS
 def test_paged_decode_step_updates_pool_in_place(one_chip, cfg):
-    """At the ``<model>.rag-decode`` benchmark cells' shapes (32 slots,
-    s_max 768, 16 spare pages: 1552 pages) the donated pool is written in
+    """At the benchmark cells' shapes the donated pool is written in
     place: the step needs less scratch memory than one layer's K and V
     pages, and neither copies nor dynamic-update-slices a whole pool (a
     pool passed through the layer scan as xs/ys costs a second pool, its
     write-back and a copy of each).  With the weights, the pool, the
     encoder and the index resident, the step fits one chip."""
-    slots, s_max = 32, 768
-    params = _spec(tr.abstract_params(cfg, jnp.bfloat16), one_chip)
-    pool = _paged_pool(one_chip, slots, s_max, cfg)
-    compiled = _compile_paged_decode(one_chip, params, pool, slots, s_max,
-                                     cfg)
+    compiled, pool = _cell_decode(one_chip, cfg)
     shape = pool["k"].shape
     layer_bytes = 2 * math.prod(shape[1:]) * pool["k"].dtype.itemsize
     mem = compiled.memory_analysis()
@@ -224,6 +234,28 @@ def test_paged_decode_step_updates_pool_in_place(one_chip, cfg):
              + mem.output_size_in_bytes - mem.alias_size_in_bytes
              + _serving_extras(cfg))
     assert total < HBM_BYTES, (total, HBM_BYTES)
+
+
+@CELLS
+def test_paged_decode_step_kernel_reads_whole_pool(one_chip, cfg):
+    """At the benchmark cells' shapes the paged kernel reads its pages
+    straight from the carried pool: its K and V operands are the whole
+    pool viewed as (L*P, page, row), and no op in the step yields one
+    layer's (P, page, row) pages -- a per-layer slice, which XLA lowers
+    to a read of that layer's whole K and V pool into VMEM."""
+    compiled, pool = _cell_decode(one_chip, cfg)
+    n_layers, n_pages, page, row = pool["k"].shape
+    text = compiled.as_text()
+    layer = re.compile(r"= \w+\[%d,%d,%d\]\{" % (n_pages, page, row))
+    sliced = [ln for ln in text.splitlines() if layer.search(ln)]
+    assert not sliced, sliced
+    flat = "bf16[%d,%d,%d]" % (n_layers * n_pages, page, row)
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls
+    for ln in calls:
+        operands = ln.split("operand_layout_constraints={", 1)[1]
+        assert operands.count(flat) == 2, (flat, ln[:600])
 
 
 def test_granite_paged_chunk_extend_compiles(one_chip, granite_params):
